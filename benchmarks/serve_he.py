@@ -625,7 +625,9 @@ def run(params, *, batch: int, mul_requests: int, rot_requests: int,
 
 
 def main(argv=None):
+    from repro.configs.heaan_mul import CONFIG
     from repro.core.params import HEParams
+    from repro.launch.compile_cache import enable_compile_cache
 
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--full", action="store_true",
@@ -643,9 +645,9 @@ def main(argv=None):
     ap.add_argument("--out", default="BENCH_serve_he.json")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     if args.full:
-        params = HEParams(logN=16, logQ=1200, logp=30, log_delta=30,
-                          beta_bits=32)
+        params = CONFIG
     else:
         params = HEParams(logN=args.logn, logQ=args.logq, logp=args.logp,
                           log_delta=args.logp, beta_bits=32,

@@ -18,8 +18,16 @@ iCRT strategies:
   - "naive"  : Algo 5 — scalar×BigInt accumulation per coefficient
                (N-degree parallelism only). Kept as the measurable baseline.
   - "acc3"   : Algo 6 loop-reordered with 3-word accumulators.
+  - "sum16"  : Algo 6 loop-reordered as four exact word-sized sums over the
+               prime axis (the half-word pieces of each product). Limb
+               dtype only, so it compiles for every backend, and a
+               model-sharded prime axis lowers to all-reduces.
   - "matmul" : Algo 6 realized as integer GEMMs on 16-bit table halves
                (β=2³² only) — N·PLimbs parallelism handed to the MXU/BLAS.
+
+The "matmul" strategies run their GEMMs in u64, which the TPU compiler
+refuses; they stay for the Table VII–IX ladders. The served defaults are
+"acc3" CRT and "sum16" iCRT.
 
 All paths are exact; tests cross-check every strategy against python-int
 oracles and against each other.
@@ -47,7 +55,7 @@ __all__ = ["crt", "icrt", "finalize_accum"]
 
 @partial(jax.jit, static_argnames=("strategy",))
 def crt(x: jnp.ndarray, tb: jnp.ndarray, tb_shoup: jnp.ndarray,
-        primes: jnp.ndarray, *, strategy: str = "matmul") -> jnp.ndarray:
+        primes: jnp.ndarray, *, strategy: str = "acc3") -> jnp.ndarray:
     """mod(Σ_k x[n,k]·β^k, p_j) for every coefficient n and prime j.
 
     x: (N, K) limbs; tb/tb_shoup: (np, K) = β^k mod p_j; primes: (np,).
@@ -132,7 +140,7 @@ def icrt(r: jnp.ndarray, tabs: IcrtTables, primes: jnp.ndarray,
          inv_P: jnp.ndarray, inv_P_shoup: jnp.ndarray,
          pdivp: jnp.ndarray, P_limbs: jnp.ndarray, P_half: jnp.ndarray,
          p_inv_f64: jnp.ndarray, out_limbs: int,
-         *, strategy: str = "matmul") -> jnp.ndarray:
+         *, strategy: str = "sum16") -> jnp.ndarray:
     """Reconstruct centered BigInts from RNS residues (paper Algo 5/6).
 
     r: (np, N). Returns (N, out_limbs) two's-complement (low limbs of the
@@ -158,7 +166,9 @@ def _icrt_jit(r, primes, inv_P, inv_P_shoup, pdivp, P_limbs, P_half,
                         primes[:, None])
 
     # (2) accum[n] = Σ_j temp[j,n]·(P/p_j)  — strategy-dependent
-    if strategy == "matmul":
+    if strategy == "sum16":
+        accum = _accum_sum16(temp, pdivp, accum_limbs)
+    elif strategy == "matmul":
         accum = _accum_matmul_u32(temp, pdivp, accum_limbs)
     elif strategy == "acc3":
         accum = _accum_acc3(temp, pdivp, accum_limbs)
@@ -204,6 +214,53 @@ def _sext(a, out_limbs):
                     jnp.zeros((), a.dtype))
     pad = jnp.broadcast_to(pad, a.shape[:-1] + (out_limbs - a.shape[-1],))
     return jnp.concatenate([a, pad.astype(a.dtype)], axis=-1)
+
+
+def _accum_sum16(temp, pdivp, accum_limbs):
+    """Loop-reordered Algo 6 as four word-sized sums over the prime axis.
+
+    Each word product temp[j,n]·pdivp[j,k] = hi·β + lo is cut into its
+    four 16-bit pieces, and every piece is summed over j on its own: a
+    sum of np < 2^16 pieces below 2^16 fits one word, so all four sums
+    are exact in the limb dtype. They are plain reductions over primes,
+    which GSPMD lowers to all-reduces when primes are model-sharded.
+    A single carry scan then places the pieces at bit offsets 0, 16, 32
+    and 48 of limb k.
+    """
+    npn, N = temp.shape
+    PL = pdivp.shape[1]
+    dt = temp.dtype
+    h = jnp.dtype(dt).itemsize * 4
+    mask = jnp.asarray((1 << h) - 1, dt)
+    assert npn < (1 << h), "piece sums would overflow a word"
+    hi, lo = mul_wide(temp[:, :, None], pdivp[:, None, :])  # (np, N, PL)
+
+    def total(x):
+        return jnp.sum(x, axis=0, dtype=dt)                  # (N, PL)
+
+    sums = [_placed(total(lo & mask), 0, accum_limbs),
+            _placed(total(lo >> h), 0, accum_limbs),
+            _placed(total(hi & mask), 0, accum_limbs),
+            _placed(total(hi >> h), 0, accum_limbs)]
+
+    def carry_step(carry, col):
+        # limb t collects s0[t] + s1[t]·2^h (low half), the high half of
+        # s1[t-1], s2[t-1], s3[t-1]·2^h (low half) and the high half of
+        # s3[t-2]: at most 6 terms, so the carry out stays below 8
+        c_in, s1_1, s2_1, s3_1, s3_2 = carry
+        s0, s1, s2, s3 = col
+        acc, c = c_in, jnp.zeros_like(c_in)
+        for term in (s0, (s1 & mask) << h, s1_1 >> h, s2_1,
+                     (s3_1 & mask) << h, s3_2 >> h):
+            nxt = acc + term
+            c = c + (nxt < term).astype(dt)
+            acc = nxt
+        return (c, s1, s2, s3, s3_1), acc
+
+    zero = jnp.zeros((N,), dt)
+    _, limbs = jax.lax.scan(carry_step, (zero,) * 5,
+                            tuple(jnp.moveaxis(s, -1, 0) for s in sums))
+    return jnp.moveaxis(limbs, 0, -1)
 
 
 def _accum_matmul_u32(temp, pdivp, accum_limbs):

@@ -28,9 +28,10 @@ __all__ = ["PipelineConfig", "to_eval", "to_eval_small", "from_eval",
 
 @dataclasses.dataclass(frozen=True)
 class PipelineConfig:
-    """Paper optimization toggles (§V). Defaults = fastest pure-JAX path."""
-    crt_strategy: str = "matmul"      # matmul | shoup | mod2 | mod4 | acc3
-    icrt_strategy: str = "matmul"     # matmul | acc3 | naive
+    """Paper optimization toggles (§V). Defaults = the served path, which
+    compiles for every backend (the TPU refuses the u64 matmul GEMMs)."""
+    crt_strategy: str = "acc3"        # acc3 | shoup | mod2 | mod4 | matmul
+    icrt_strategy: str = "sum16"      # sum16 | acc3 | naive | matmul
     modified_shoup: bool = False      # paper's 3-half-mul Shoup variant
     use_kernels: bool = False         # route stages through Pallas kernels
 

@@ -349,8 +349,8 @@ class StageFns:
 
 
 def make_stage_fns(st: HEStatic, mesh: Mesh, *,
-                   crt_strategy: str = "matmul",
-                   icrt_strategy: str = "matmul",
+                   crt_strategy: str = "acc3",
+                   icrt_strategy: str = "sum16",
                    modified_shoup: bool = False,
                    reduce_scatter_icrt: bool = False,
                    use_kernels: bool = False,
@@ -532,8 +532,8 @@ def make_keyswitch_step(st: HEStatic, sf: StageFns):
 
 
 def make_he_mul_step(st: HEStatic, mesh: Mesh, *,
-                     crt_strategy: str = "matmul",
-                     icrt_strategy: str = "matmul",
+                     crt_strategy: str = "acc3",
+                     icrt_strategy: str = "sum16",
                      modified_shoup: bool = False,
                      reduce_scatter_icrt: bool = False,
                      use_kernels: bool = False,

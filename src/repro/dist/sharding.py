@@ -111,17 +111,18 @@ def he_expected_collectives(op: str, mesh: Mesh, params, logq: int, *,
                             batch: int, n_slots: Optional[int] = None
                             ) -> dict:
     """Predicted collective schedule of one served (op, level) cell under
-    the placements above, with the default "matmul" iCRT strategy.
+    the placements above, with the default "sum16" iCRT strategy.
 
     Only iCRT's cross-prime accumulation communicates: every residue
     tensor is (B, np, N) with np on "model", every stage before iCRT is
     prime-pointwise, and the batch axes make every op batch-pointwise —
-    so each iCRT reduction lowers to EXACTLY three all-reduces over the
-    model-axis groups:
+    so each iCRT reduction lowers to EXACTLY five all-reduced tensors
+    over the model-axis groups (XLA may combine them into fewer tuple
+    instructions; `launch.hlo_analysis` counts tensors):
 
-      2 × u64[B_local, N, plimbs]   the partial-product accumulator
-                                    halves of the Σ_j x_j·(P/p_j) matmul
-                                    (plimbs = limb width of P/p_j, from
+      4 × u32[B_local, N, plimbs]   the half-word piece sums of
+                                    Σ_j x_j·(P/p_j) (plimbs = limb width
+                                    of P/p_j, from
                                     `core.context.build_icrt_tables`);
       1 × f64[B_local, N]           the quotient estimate Σ x_j/p_j that
                                     picks the exact ±1-corrected k·P.
@@ -175,12 +176,12 @@ def he_expected_collectives(op: str, mesh: Mesh, params, logq: int, *,
         if not n_r:
             continue
         plimbs = build_icrt_tables(params, npn).plimbs
-        one = 2 * ring(b_local * params.N * plimbs * 8) \
+        one = 4 * ring(b_local * params.N * plimbs * 4) \
             + ring(b_local * params.N * 8)
         per_region.append({"reductions": n_r, "np": npn,
                            "plimbs": plimbs, "bytes_per_reduction": one})
         total += n_r * one
-    return {"kinds": ["all-reduce"], "counts": {"all-reduce": 3 * n_red},
+    return {"kinds": ["all-reduce"], "counts": {"all-reduce": 5 * n_red},
             "wire_bytes": total, "n_reductions": n_red, "axis": "model",
             "group_size": g, "per_region": per_region, "allowed": allowed}
 

@@ -219,6 +219,8 @@ class HEFrontend(HEServer):
         default; simulated multi-host, shares this process's devices) or
         "subprocess" (real `python -m repro.hserve.worker` processes,
         each with its own XLA host devices).
+    worker_platform: ``JAX_PLATFORMS`` of each subprocess worker; required
+        with transport="subprocess" (see `SubprocessTransport`).
     worker_devices: host device count per subprocess worker.
     injector: optional `runtime.failures.FailureInjector` whose
         `kill_worker_at` schedule this frontend consults after every
@@ -250,6 +252,7 @@ class HEFrontend(HEServer):
                  heartbeat_dir: Optional[str] = None,
                  heartbeat_timeout: float = 30.0,
                  heartbeat_interval: float = 0.0,
+                 worker_platform: Optional[str] = None,
                  worker_devices: int = 1,
                  **engine_knobs):
         if workers < 1:
@@ -257,6 +260,9 @@ class HEFrontend(HEServer):
         if transport not in ("inproc", "subprocess"):
             raise ValueError(f"unknown transport {transport!r} "
                              "(inproc | subprocess)")
+        if transport == "subprocess" and worker_platform is None:
+            raise ValueError("subprocess workers need an explicit "
+                             "worker_platform (e.g. 'cpu')")
         if mesh is None:
             from repro.launch.mesh import make_host_mesh
             mesh = make_host_mesh()
@@ -300,7 +306,8 @@ class HEFrontend(HEServer):
                     **engine_knobs)
                 tp = InProcTransport(eng)
             else:
-                tp = SubprocessTransport(devices=worker_devices)
+                tp = SubprocessTransport(worker_platform,
+                                         devices=worker_devices)
                 self._send_worker_init(tp, wid, hb_path)
             self.workers.append(WorkerHandle(wid, tp,
                                              heartbeat_path=hb_path))

@@ -747,7 +747,11 @@ class HEServer:
             **({"stages": st.summary()} if st is not None else {}),
             "cache": self.cache.stats(),
             "engine": {"steps_compiled": self.engine.n_compiled,
-                       "compile_s": round(self.engine.compile_s, 3)},
+                       "compile_s": round(self.engine.compile_s, 3),
+                       "compile_s_by_bucket": {
+                           "/".join(str(k) for k in key if k is not None):
+                           round(v, 3) for key, v in
+                           self.engine.compile_s_by_key.items()}},
             "mesh": dict(self.mesh.shape),
             "batch": self.batch,
             "flush_policy": {

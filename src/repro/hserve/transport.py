@@ -179,15 +179,43 @@ class InProcTransport:
         self._replies.clear()
 
 
+def _parent_holds_accelerator() -> bool:
+    """True once this process has initialised a non-CPU jax backend.
+
+    Only already-initialised backends are inspected: asking jax for its
+    devices would itself claim the chip.
+    """
+    if "jax" not in sys.modules:
+        return False
+    from jax._src import xla_bridge
+    if not xla_bridge.backends_are_initialized():
+        return False
+    import jax
+    return any(d.platform != "cpu" for d in jax.devices())
+
+
 class SubprocessTransport:
-    """Frames over the stdin/stdout pipes of a spawned worker process."""
+    """Frames over the stdin/stdout pipes of a spawned worker process.
+
+    `platform` is the child's ``JAX_PLATFORMS`` ("cpu", "tpu", ...). It
+    has no default: a chip belongs to one process at a time, so a child
+    that needs the chip the parent already holds would fail or hang —
+    that case raises here instead of spawning. `devices` forces the
+    child's host device count on the CPU platform.
+    """
 
     kind = "subprocess"
 
-    def __init__(self, *, devices: int = 1, env: Mapping[str, str] | None = None,
-                 ) -> None:
+    def __init__(self, platform: str, *, devices: int = 1,
+                 env: Mapping[str, str] | None = None) -> None:
+        if platform != "cpu" and _parent_holds_accelerator():
+            raise RuntimeError(
+                f"this process already holds the accelerator; a "
+                f"{platform!r} worker subprocess could not get the chip. "
+                "Use in-process workers (transport='inproc') instead.")
         # spawn args are kept so :meth:`respawn` can relaunch an
         # identical process after a crash
+        self._platform = platform
         self._devices = devices
         self._env = dict(env) if env else None
         self.proc = self._spawn()
@@ -202,9 +230,10 @@ class SubprocessTransport:
         penv.update(self._env or {})
         pp = penv.get("PYTHONPATH", "")
         penv["PYTHONPATH"] = src_dir + (os.pathsep + pp if pp else "")
-        penv["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={self._devices}")
-        penv.setdefault("JAX_PLATFORMS", "cpu")
+        penv["JAX_PLATFORMS"] = self._platform
+        if self._platform == "cpu":
+            penv["XLA_FLAGS"] = (
+                f"--xla_force_host_platform_device_count={self._devices}")
         # -c instead of -m: the package __init__ imports the worker
         # module, so `-m` would re-execute it as __main__ (runpy warns)
         return subprocess.Popen(

@@ -84,13 +84,13 @@ class WorkerEngine:
                  heartbeat_path=None, heartbeat_interval: float = 0.0,
                  heartbeat_clock: Optional[Callable[[], float]] = None,
                  **engine_knobs):
-        import jax
         from repro.hserve.engine import OpEngine
+        from repro.launch.mesh import make_mesh
 
         self.params = params
         self.wid = wid
         self.mesh = mesh if mesh is not None else \
-            jax.make_mesh((1, 1), ("data", "model"))
+            make_mesh((1, 1), ("data", "model"))
         self.cache = TableCache(params, evk, rot_keys, conj_key)
         self.engine = OpEngine(params, self.mesh, self.cache,
                                **engine_knobs)
@@ -205,11 +205,11 @@ def main() -> None:
     head, arrays = read_frame(inp)
     if head["type"] != "init":
         raise SystemExit(f"expected init frame, got {head['type']!r}")
-    import jax
+    from repro.launch.mesh import make_mesh
 
     params = HEParams(**head["params"])
     evk, rot_keys, conj_key = _keys_from_init(head, arrays)
-    mesh = jax.make_mesh(tuple(head["mesh"]), ("data", "model"))
+    mesh = make_mesh(tuple(head["mesh"]), ("data", "model"))
     hb = head.get("heartbeat") or {}
     worker = WorkerEngine(
         params, evk, rot_keys, conj_key, mesh=mesh,

@@ -25,7 +25,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.wordops import acc3_add_product, shoup_modmul
-from repro.kernels.common import pick_block, use_interpret
+from repro.kernels.common import ZERO, pick_block, use_interpret
 
 
 def _icrt_kernel(r_ref, invp_ref, invp_sh_ref, pdivp_ref, qfix_ref, p_ref,
@@ -94,20 +94,20 @@ def icrt_accum_pallas(r, inv_P, inv_P_shoup, pdivp, quot_fix, primes, *,
     PL = pdivp.shape[1]
     nb = pick_block(N, 128)
     interp = use_interpret() if interpret is None else interpret
-    col = pl.BlockSpec((npn, 1), lambda i: (0, 0))
+    col = pl.BlockSpec((npn, 1), lambda i: (ZERO, ZERO))
     acc, s = pl.pallas_call(
         _icrt_kernel,
         grid=(N // nb,),
         in_specs=[
-            pl.BlockSpec((npn, nb), lambda i: (0, i)),
+            pl.BlockSpec((npn, nb), lambda i: (ZERO, i)),
             col, col,
-            pl.BlockSpec((npn, PL), lambda i: (0, 0)),
-            pl.BlockSpec((npn, 2), lambda i: (0, 0)),
+            pl.BlockSpec((npn, PL), lambda i: (ZERO, ZERO)),
+            pl.BlockSpec((npn, 2), lambda i: (ZERO, ZERO)),
             col,
         ],
         out_specs=[
-            pl.BlockSpec((nb, accum_limbs), lambda i: (i, 0)),
-            pl.BlockSpec((nb, 1), lambda i: (i, 0)),
+            pl.BlockSpec((nb, accum_limbs), lambda i: (i, ZERO)),
+            pl.BlockSpec((nb, 1), lambda i: (i, ZERO)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((N, accum_limbs), r.dtype),

@@ -22,7 +22,6 @@ memory where the backend reports it, and wall compile time.
 """
 
 import argparse
-import contextlib
 import json
 import sys
 import time
@@ -117,34 +116,8 @@ def lower_lm_cell(arch: str, shape_name: str, mesh, *,
     return out
 
 
-@contextlib.contextmanager
-def _x64_disabled():
-    """LM cells lower with 32-bit index types.
-
-    repro.core enables x64 globally for the HE limb pipeline (f64 iCRT
-    quotients, u64 limbs), but s64 scan indices trip an XLA SPMD
-    partitioner bug (s64/s32 compare in the scan-transpose
-    dynamic-update-slice) when the scanned params are sharded. The LM
-    model code is dtype-explicit, so 32-bit tracing is value-identical.
-    """
-    old = jax.config.jax_enable_x64
-    jax.config.update("jax_enable_x64", False)
-    try:
-        yield
-    finally:
-        jax.config.update("jax_enable_x64", old)
-
-
 def _lower_lm_variant(cfg, shape_name: str, mesh, opt_dtype=None,
                       sharding_mode: str = "fsdp") -> dict:
-    with _x64_disabled():
-        return _lower_lm_variant_inner(cfg, shape_name, mesh,
-                                       opt_dtype=opt_dtype,
-                                       sharding_mode=sharding_mode)
-
-
-def _lower_lm_variant_inner(cfg, shape_name: str, mesh, opt_dtype=None,
-                            sharding_mode: str = "fsdp") -> dict:
     kind, seq_len, global_batch = SHAPES[shape_name]
     params_abs = _abstract_params(cfg)
     p_sh = param_sharding_rules(params_abs, mesh,

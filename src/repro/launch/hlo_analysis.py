@@ -146,6 +146,10 @@ def collective_bytes_from_hlo(hlo_text: str, *,
     (operands..., outputs...) so only the output half is sized.
     `default_group_size` backs the empty `replica_groups={}` form (all
     participants — pass the device count of the program).
+
+    An all-reduce counts once per tensor it reduces: XLA's combiner
+    merges independent all-reduces into one tuple instruction, and that
+    grouping is scheduling, not dataflow.
     """
     out = {k: 0.0 for k in _COLLECTIVES}
     counts = {k: 0 for k in _COLLECTIVES}
@@ -188,7 +192,7 @@ def collective_bytes_from_hlo(hlo_text: str, *,
             wire = size * (g - 1) / g
         else:
             wire = float(size)
-        counts[base] += 1
+        counts[base] += max(1, len(shapes)) if base == "all-reduce" else 1
         out[base] += wire
         ops.append({"op": base, "async": suf == "-start",
                     "size_bytes": size, "group_size": g,
@@ -203,8 +207,6 @@ def collective_bytes_from_hlo(hlo_text: str, *,
 def analyze_compiled(lowered, compiled, seconds: float) -> dict:
     """Cost/memory/collective/fusion record for one compiled cell."""
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):    # some jax versions: one per program
-        cost = cost[0] if cost else {}
     try:
         mem = compiled.memory_analysis()
         mem_d = {

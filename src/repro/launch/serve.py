@@ -7,7 +7,7 @@ repro.hserve runtime (queue → level-aware table cache → sharded engine).
     PYTHONPATH=src python -m repro.launch.serve --he --batch 8 \
         --requests 24 --levels 3 --rotations 4 --conjugations 2 \
         [--plain-frac 0.5] [--circuit] [--schedule] [--max-age-s 0.05] \
-        [--overlap] [--kernels]
+        [--overlap] [--kernels] [--full]
 
 Both paths place their state with repro.dist.sharding rules on the host
 mesh (whatever devices this process has), so the same driver scales from
@@ -17,6 +17,7 @@ mesh (whatever devices this process has), so the same driver scales from
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
 
 import jax
@@ -45,6 +46,48 @@ def generate(params, cfg: ModelConfig, tokens, gen_steps: int,
     return jnp.concatenate(out, axis=1)
 
 
+def core_reference(op: str, operands, params, keys: dict):
+    """One served op recomputed by the single-device `core` functions on
+    the CPU backend, from host copies of its operands and keys.
+
+    keys: "evk" (EvalKey), and the served "rot" (r = 1) / "conj" key
+    pytrees where the op needs them. Returns a Ciphertext of numpy
+    arrays.
+    """
+    from repro.core import heaan as H
+    from repro.core.cipher import Ciphertext, EvalKey
+    from repro.core.rotate import he_conjugate, he_rotate
+
+    def host(x):
+        if isinstance(x, Ciphertext):
+            return dataclasses.replace(x, ax=np.asarray(x.ax),
+                                       bx=np.asarray(x.bx))
+        if isinstance(x, EvalKey):
+            return EvalKey(*(np.asarray(getattr(x, f.name))
+                             for f in dataclasses.fields(x)))
+        if isinstance(x, dict):                   # served key pytree
+            return EvalKey(**{k: np.asarray(v) for k, v in x.items()})
+        return np.asarray(x)
+
+    args = [host(x) for x in operands]
+    with jax.default_device(jax.devices("cpu")[0]):
+        if op == "mul":
+            out = H.he_mul(*args, host(keys["evk"]), params)
+        elif op == "mul_plain":
+            out = H.he_mul_plain(*args, params)
+        elif op == "add_plain":
+            out = H.he_add_plain(*args, params)
+        elif op == "rotate":
+            out = he_rotate(*args, 1, host(keys["rot"]), params)
+        elif op == "conjugate":
+            out = he_conjugate(*args, host(keys["conj"]), params)
+        elif op == "rescale":
+            out = H.rescale(*args, params)
+        else:
+            raise ValueError(f"no core reference for op {op!r}")
+        return host(out)
+
+
 def serve_he(batch: int, requests: int = 0, levels: int = 1,
              rotations: int = 0, conjugations: int = 0,
              plain_frac: float = 0.0, model_shards: int = 1,
@@ -54,7 +97,7 @@ def serve_he(batch: int, requests: int = 0, levels: int = 1,
              check: str = "off", seed: int = 0,
              trace: str | None = None, profile_stages: bool = False,
              metrics: str | None = None, workers: int = 0,
-             bootstrap: int = 0) -> dict:
+             bootstrap: int = 0, params=None) -> dict:
     """Batched multi-level HE serving, driven through a `repro.client`
     HESession (the session owns keygen, encrypt/decrypt, and the
     HEServer; the raw per-op stream rides `session.server`).
@@ -94,6 +137,16 @@ def serve_he(batch: int, requests: int = 0, levels: int = 1,
     documented error bound (approximate, not bitwise); the returned
     stats gain a "bootstrap" block with the measured error, the bound,
     and the cross-circuit co-batch rate the concurrent pipelines hit.
+
+    `params` picks the HEAAN parameter set (default `configs.heaan_mul`
+    SMOKE; `CONFIG` is the paper's Table III). Every mul and mul_plain
+    result is rescaled by the server before it is decrypted.
+
+    Besides decrypting, the first request of every (op, level) bucket of
+    the raw per-op stream is recomputed by the single-device `core`
+    functions on the CPU backend (``jax.devices("cpu")``) and must equal
+    the served result bit for bit; "bitwise_checked" in the returned
+    stats counts the results so compared.
     """
     from repro.client import HESession
     from repro.configs.heaan_mul import SMOKE
@@ -105,8 +158,11 @@ def serve_he(batch: int, requests: int = 0, levels: int = 1,
 
     if bootstrap:
         from repro.boot import boot_params
+        if params is not None:
+            raise ValueError("--bootstrap runs at boot_params(); "
+                             "it takes no other parameter set")
         params = boot_params()
-    else:
+    elif params is None:
         params = SMOKE
     requests = requests or 2 * batch + 1   # force >1 batch and padding
     # the lowest level logq = logp is excluded: mul results there cannot
@@ -146,6 +202,7 @@ def serve_he(batch: int, requests: int = 0, levels: int = 1,
     n = params.n_slots_max
     logqs = [params.logQ - i * params.logp for i in range(levels)]
     expect = {}   # rid -> (op, expected slots)
+    probes = {}   # (op, logq) -> (rid, operands): the reference's inputs
     n_mul = requests - rotations - conjugations
     if n_mul < 0:
         raise ValueError(
@@ -163,21 +220,29 @@ def serve_he(batch: int, requests: int = 0, levels: int = 1,
             w = rng.normal(size=n) + 1j * rng.normal(size=n)
             pt = H.encode_plain(w, params, logq)
             if i % 2 == 0:
-                expect[server.submit_mul_plain(ct, pt)] = \
-                    ("mul_plain", z * w)
+                op, rid = "mul_plain", server.submit_mul_plain(ct, pt)
+                expect[rid] = (op, z * w)
             else:
-                expect[server.submit_add_plain(ct, pt)] = \
-                    ("add_plain", z + w)
+                op, rid = "add_plain", server.submit_add_plain(ct, pt)
+                expect[rid] = (op, z + w)
+            operands = (ct, pt)
         elif i < n_mul:
             z2 = rng.normal(size=n) + 1j * rng.normal(size=n)
             c2 = session.encrypt(z2, seed=2 * i + 2).ciphertext
             if logq < params.logQ:
                 c2 = H.he_mod_down(c2, params, logq)
-            expect[server.submit_mul(ct, c2)] = ("mul", z * z2)
+            op, rid = "mul", server.submit_mul(ct, c2)
+            expect[rid] = (op, z * z2)
+            operands = (ct, c2)
         elif i < n_mul + rotations:
-            expect[server.submit_rotate(ct, 1)] = ("rotate", np.roll(z, -1))
+            op, rid = "rotate", server.submit_rotate(ct, 1)
+            expect[rid] = (op, np.roll(z, -1))
+            operands = (ct,)
         else:
-            expect[server.submit_conjugate(ct)] = ("conjugate", np.conj(z))
+            op, rid = "conjugate", server.submit_conjugate(ct)
+            expect[rid] = (op, np.conj(z))
+            operands = (ct,)
+        probes.setdefault((op, logq), (rid, operands))
 
     if circuit:
         # a degree-4 encrypted polynomial, evaluated WHOLLY server-side:
@@ -243,12 +308,17 @@ def serve_he(batch: int, requests: int = 0, levels: int = 1,
     # session.drain (not server.drain) so traced futures resolve while
     # the raw per-op/circuit results come back as {rid: ct}
     results.update(session.drain())
+    # products come back at scale Δ²: the server rescales them too
+    rescaled = {rid: server.submit_rescale(results[rid])
+                for rid, (op, _) in expect.items()
+                if op in ("mul", "mul_plain")}
+    results.update(session.drain())
+    for rid, rrid in rescaled.items():
+        probes.setdefault(("rescale", results[rid].logq),
+                          (rrid, (results[rid],)))
     errs = []
     for rid, (op, want) in expect.items():
-        out = results[rid]
-        if op in ("mul", "mul_plain"):
-            out = H.rescale(out, params)
-        got = session.decrypt(out)
+        got = session.decrypt(results[rescaled.get(rid, rid)])
         errs.append(float(np.abs(got - want).max()))
     for fut, want in tfuts:
         errs.append(float(np.abs(session.decrypt(fut.result())
@@ -256,6 +326,20 @@ def serve_he(batch: int, requests: int = 0, levels: int = 1,
     stats = server.stats()
     stats["devices"] = len(jax.devices())
     stats["max_err"] = max(errs)
+    keys = {"evk": session.evk}
+    if rotations:
+        keys["rot"] = server.cache.rot_key(1)
+    if conjugations:
+        keys["conj"] = server.cache.conj_key()
+    for (op, logq), (rid, operands) in sorted(probes.items()):
+        want = core_reference(op, operands, params, keys)
+        got = results[rid]
+        if not (got.logq == want.logq and got.logp == want.logp
+                and np.array_equal(np.asarray(got.ax), want.ax)
+                and np.array_equal(np.asarray(got.bx), want.bx)):
+            raise AssertionError(
+                f"served {op} at logq={logq} differs from core")
+    stats["bitwise_checked"] = len(probes)
     if bootstrap:
         # approximate-op contract: error-BOUND gate, not bitwise
         plan = next(iter(session._boot_plans.values()))
@@ -383,13 +467,21 @@ def main():
                          "Switches the run to the reference bootstrap "
                          "params (logQ=336, h=2); results verify "
                          "against the documented error bound")
+    ap.add_argument("--full", action="store_true",
+                    help="serve at the paper's Table III params "
+                         "(configs.heaan_mul.CONFIG: logN=16, "
+                         "logQ=1200) instead of the logN=5 smoke set — "
+                         "the TPU target's configuration")
     ap.add_argument("--metrics", default=None, metavar="PATH",
                     help="dump the unified MetricsRegistry snapshot "
                          "(serve/cache/scheduler/engine/client planes) "
                          "as JSON after the drain")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.he:
+        from repro.configs.heaan_mul import CONFIG
         stats = serve_he(args.batch, requests=args.requests,
                          levels=args.levels, rotations=args.rotations,
                          conjugations=args.conjugations,
@@ -402,7 +494,8 @@ def main():
                          trace=args.trace,
                          profile_stages=args.profile_stages,
                          metrics=args.metrics, workers=args.workers,
-                         bootstrap=args.bootstrap)
+                         bootstrap=args.bootstrap,
+                         params=CONFIG if args.full else None)
         ops = ", ".join(
             f"{op}: {d['requests']} reqs @ {d['ops_per_s']}/s "
             f"(p50 {d['latency_ms']['p50']}ms, "
@@ -451,6 +544,8 @@ def main():
                   f"{args.trace}")
         if args.metrics:
             print(f"  metrics snapshot -> {args.metrics}")
+        print(f"  bitwise vs core (CPU): {stats['bitwise_checked']} "
+              "results equal")
         print(f"  max_err {stats['max_err']:.2e}")
         assert stats["max_err"] < 1e-2, "HE serving pipeline diverged"
         return

@@ -33,6 +33,7 @@ _PREAMBLE = """
     import jax.numpy as jnp
     import numpy as np
     import repro.core
+    from repro.launch.mesh import make_mesh
 """
 
 

@@ -37,6 +37,7 @@ from repro.core.keys import keygen
 from repro.core.rotate import conj_keygen, rot_keygen
 from repro.hserve import HEServer
 from repro.obs import Tracer
+from repro.launch.mesh import make_mesh
 
 PARAMS = boot_params()              # logN=4, logQ=336, logp=24, h=2
 
@@ -63,7 +64,7 @@ class BootEnv:
         self.tracer = Tracer()
         self.server = HEServer(
             PARAMS, self.evk, self.rot, self.conj,
-            mesh=jax.make_mesh((1, 1), ("data", "model")),
+            mesh=make_mesh((1, 1), ("data", "model")),
             batch=2, schedule=True, tracer=self.tracer)
         self.plan = bootstrap_circuit(
             PARAMS, logq_in=PARAMS.logp,
@@ -355,7 +356,7 @@ def test_bootstrap_cobatch_and_mod_raise_on_8_device_mesh(
         sk, pk, evk = keygen(params, seed=0)
         rot = {r: rot_keygen(params, sk, r) for r in (1, 2, 3, 4)}
         conj = conj_keygen(params, sk)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         srv = HEServer(params, evk, rot, conj, mesh=mesh, batch=2,
                        schedule=True)
         plan = bootstrap_circuit(params, logq_in=params.logp,

@@ -26,6 +26,7 @@ from repro.core.rotate import conj_keygen, he_conjugate, he_rotate, \
     rot_keygen
 from repro.hserve import CircuitOp, HEServer
 from repro.hserve.circuit import execute_circuit_reference
+from repro.launch.mesh import make_mesh
 
 # logp=24 over logQ=120 leaves L=5: depth-2 traces keep two spare levels
 PARAMS = small_params(logN=4, beta_bits=32, logQ=120, logp=24)
@@ -33,7 +34,7 @@ PARAMS = small_params(logN=4, beta_bits=32, logQ=120, logp=24)
 
 @pytest.fixture(scope="module")
 def session():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     return HESession(PARAMS, seed=0, mesh=mesh, batch=2)
 
 
@@ -75,7 +76,7 @@ def test_trace_is_lazy_and_plain_arithmetic_folds(session):
 
 
 def test_trace_time_validation():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     s1 = HESession(PARAMS, seed=0, mesh=mesh, batch=2)
     s2 = HESession(PARAMS, seed=1, mesh=mesh, batch=2)
     x1, x2 = s1.encrypt(_msg(1), seed=1), s2.encrypt(_msg(2), seed=2)
@@ -229,7 +230,7 @@ def test_rejected_plain_operand_does_not_poison_cache():
     """A pt that fails queue validation must NOT be registered — a
     later hash-only circuit would resolve the bad resident and fail
     mid-drain."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     server = HEServer(PARAMS, mesh=mesh, batch=2)
     s = HESession(PARAMS, seed=0, server=server)
     ct = s.encrypt(_msg(17), seed=17).ciphertext
@@ -247,7 +248,7 @@ def test_run_submit_failure_leaves_results_recoverable():
     """If a LATER handle's submit fails (missing Galois key, pk-only
     session), already-enqueued circuits must not vanish into
     unreachable futures — their results come back from drain()."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     from repro.core.keys import keygen
     sk, pk, evk = keygen(PARAMS, seed=0)
     server = HEServer(PARAMS, evk, mesh=mesh, batch=2)
@@ -316,7 +317,7 @@ def test_run_rematerializes_after_lru_eviction_race():
     """A sibling's registration inside one run() can evict the entry a
     later handle compiled hash-only against; run() must re-materialize
     and serve correctly instead of raising."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     entry_mib = np.zeros(
         (PARAMS.N, PARAMS.qlimbs(PARAMS.logQ)), np.uint32).nbytes / 2**20
     server = HEServer(PARAMS, mesh=mesh, batch=2,
@@ -350,7 +351,7 @@ def test_plain_cache_hits_across_requests():
     """Affine-layer contract: the same weights at the same level encode
     and ship ONCE — the second traced run compiles to hash-only nodes
     and the server serves the operand from its (hash, level) cache."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     s = HESession(PARAMS, seed=0, mesh=mesh, batch=2)
     w = _msg(20)
     for i, expected_pt in ((0, True), (1, False)):
@@ -366,7 +367,7 @@ def test_plain_cache_hits_across_requests():
 
 
 def test_plain_cache_standalone_submit_and_unknown_hash():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     server = HEServer(PARAMS, mesh=mesh, batch=2)   # keyless: plain ops only
     s = HESession(PARAMS, seed=0, server=server)
     ct = s.encrypt(_msg(30), seed=30).ciphertext
@@ -397,7 +398,7 @@ def test_plain_cache_standalone_submit_and_unknown_hash():
 def test_plain_cache_bitwise_vs_core():
     """A cache-served mul_plain is bitwise the core reference (the
     cached buffer IS the encoding the client would have sent)."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     s = HESession(PARAMS, seed=0, mesh=mesh, batch=2)
     z, w = _msg(32), _msg(33)
     x = s.encrypt(z, seed=32)
@@ -452,7 +453,7 @@ def test_future_triggered_drain_buffers_raw_results(session):
 def test_explicit_server_loads_passed_galois_keys():
     """rot_keys/conj_key passed alongside server= must load into that
     server's cache (a pk-only session cannot regenerate them)."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     from repro.core.keys import keygen
     sk, pk, evk = keygen(PARAMS, seed=0)
     server = HEServer(PARAMS, evk, mesh=mesh, batch=2)
@@ -472,7 +473,7 @@ def test_explicit_server_loads_passed_galois_keys():
 def test_plain_cache_resident_is_read_only_and_aliased():
     """Cache-resolved operands alias the read-only resident buffer (no
     per-request copy) while caller-provided arrays are still copied."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     server = HEServer(PARAMS, mesh=mesh, batch=2)
     s = HESession(PARAMS, seed=0, server=server)
     ct = s.encrypt(_msg(74), seed=74).ciphertext
@@ -535,7 +536,7 @@ def test_traced_client_bitwise_on_8_device_mesh(run_in_8dev_subprocess):
         from repro.hserve.circuit import execute_circuit_reference
 
         params = test_params(logN=5, beta_bits=32)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         session = HESession(params, seed=0, mesh=mesh, batch=2)
         rks = {r: rot_keygen(params, session.sk, r) for r in (1, 2, 4, 8)}
         ck = conj_keygen(params, session.sk)

@@ -31,7 +31,7 @@ def test_he_pipeline_matches_core_on_mesh(run_in_8dev_subprocess):
         ref = [H.he_mul(cts[2*i], cts[2*i+1], evk, params)
                for i in range(B)]
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         st = hp.he_static(params, params.logQ)
         step = jax.jit(hp.make_he_mul_step(st, mesh))
         ctx = make_context(params, params.logQ)
@@ -59,7 +59,7 @@ def test_compressed_dp_grads_close_to_exact(run_in_8dev_subprocess):
         from jax.sharding import PartitionSpec as P
         from repro.dist.collectives import compressed_psum_grads
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         rng = np.random.default_rng(0)
         g_all = jnp.asarray(rng.normal(size=(8, 4, 333)).astype(np.float32))
 
@@ -94,7 +94,7 @@ def test_param_sharding_rules_place_and_divide(run_in_8dev_subprocess):
         cfg = get_arch("llama3.2-1b").reduced(
             d_model=64, n_heads=4, n_kv_heads=2, head_dim=16, d_ff=128,
             vocab_size=512)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         params = init_params(cfg, jax.random.key(0))
         shardings = param_sharding_rules(params, mesh)
         placed = jax.device_put(params, shardings)

@@ -24,12 +24,13 @@ from repro.dist.sharding import (
     batch_spec, cache_sharding_rules, he_limb_sharding,
     param_sharding_rules, zero1_opt_sharding,
 )
+from repro.launch.mesh import make_mesh
 
 PARAMS = small_params(logN=4, beta_bits=32)
 
 
 def _mesh11():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 # --------------------------------------------------------------------------
@@ -120,7 +121,7 @@ def test_sharded_he_mul_bitwise_on_one_device():
 # --------------------------------------------------------------------------
 
 def test_compressed_psum_grads_single_device():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     rng = np.random.default_rng(0)
     g = jnp.asarray(rng.normal(size=(8, 130)).astype(np.float32))
 
@@ -161,7 +162,7 @@ def test_trainer_compress_dp_runs_and_replays_bit_identical(tmp_path):
 
 
 def test_compressed_psum_preserves_structure_and_dtype():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     tree = {"a": jnp.ones((3, 7), jnp.float32),
             "b": {"c": jnp.full((300,), 0.25, jnp.float32)}}
 
@@ -256,5 +257,5 @@ def test_he_limb_sharding_rejects_odd_batch_on_wide_mesh():
     devs = jax.devices()
     if len(devs) < 2:
         pytest.skip("needs >1 device to exercise the divisibility check")
-    mesh = jax.make_mesh((2, len(devs) // 2), ("data", "model"))
+    mesh = make_mesh((2, len(devs) // 2), ("data", "model"))
     assert he_limb_sharding(mesh, batch=3).is_fully_replicated

@@ -9,6 +9,7 @@ import pytest
 import repro.core  # noqa: F401
 from repro.launch.hlo_analysis import (collective_bytes_from_hlo,
                                        count_fusions, parse_replica_groups)
+from repro.launch.mesh import make_mesh
 from benchmarks.roofline import analyze_record, model_flops
 
 
@@ -164,13 +165,11 @@ def test_model_flops_formulas():
 # --------------------------------------------------------------------------
 
 def _serving_lowered(op: str, batch: int = 2, logq=None):
-    import jax
-
     from repro.core.params import test_params
     from repro.launch.cells import lower_he_serving_cell
 
     params = test_params(logN=4, beta_bits=32)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     return lower_he_serving_cell(op, batch, mesh, logq=logq, params=params)
 
 

@@ -13,6 +13,7 @@ from repro.ckpt import CheckpointManager
 from repro.configs.registry import get_arch
 from repro.launch.train import TrainConfig, Trainer, run_with_restarts
 from repro.runtime import FailureInjector, StepMonitor
+from repro.launch.mesh import make_mesh
 
 
 def _cfg():
@@ -86,7 +87,7 @@ def test_elastic_reshard_restore(tmp_path):
     m = CheckpointManager(str(tmp_path), async_save=False)
     tree = {"w": jnp.arange(16, dtype=jnp.float32).reshape(4, 4)}
     m.save(1, tree, block=True)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
 
     def shard(key, arr):
         return jax.device_put(arr, NamedSharding(mesh, P("data")))

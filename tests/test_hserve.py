@@ -26,6 +26,7 @@ from repro.hserve import (
     ServeMetrics, TableCache, circuit_schedule, degree4_demo_circuit,
     slot_sum_rotations, validate_circuit,
 )
+from repro.launch.mesh import make_mesh
 
 PARAMS = small_params(logN=4, beta_bits=32)   # N=16, n_slots=8, L=5
 
@@ -81,7 +82,7 @@ def test_server_rejects_unserveable_requests_at_submit(keys):
     otherwise it fails mid-drain after being popped, taking the rest of
     the queued work down with it."""
     _, pk, evk, rks = keys
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     _, c1 = _enc(pk, 1)
     server = HEServer(PARAMS, evk, {1: rks[1]}, mesh=mesh, batch=2)
     with pytest.raises(KeyError):
@@ -205,7 +206,7 @@ def test_table_cache_keys_and_stats(keys):
 
 def _server(keys, conj_key=None, **kw):
     _, _, evk, rks = keys
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     return HEServer(PARAMS, evk, rks, conj_key, mesh=mesh, batch=2, **kw)
 
 
@@ -363,7 +364,7 @@ def test_served_plain_ops_bitwise_equal_core_at_every_level(keys):
     sk, pk, _, _ = keys
     # a server with NO evk / rotation / conjugation keys at all: the
     # plaintext ops must still serve (no key switch is their point)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     server = HEServer(PARAMS, mesh=mesh, batch=2)
     cases = []
     for i in range(3):
@@ -820,7 +821,7 @@ def test_post_idle_trickle_flushes_at_adapted_target(keys):
     inflated, and every post-idle trickle request waited the full
     max_age_s before the age deadline flushed it."""
     _, _, evk, rks = keys
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     now = [0.0]
     server = HEServer(PARAMS, evk, rks, mesh=mesh, batch=4,
                       max_age_s=2.0, clock=lambda: now[0])
@@ -850,7 +851,7 @@ def test_adaptive_bucket_target_flushes_below_batch(keys):
     """At a low observed arrival rate the full-bucket target shrinks to
     rate × max_age_s, so a bucket that will never fill stops waiting."""
     _, _, evk, rks = keys
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     now = [0.0]
     server = HEServer(PARAMS, evk, rks, mesh=mesh, batch=4,
                       max_age_s=2.0, clock=lambda: now[0])
@@ -955,7 +956,7 @@ def test_hserve_ops_bitwise_on_8_device_mesh(run_in_8dev_subprocess):
         sk, pk, evk = keygen(params, seed=0)
         rks = {r: rot_keygen(params, sk, r) for r in (1, 2, 4, 8)}
         ckey = conj_keygen(params, sk)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         server = HEServer(params, evk, rks, ckey, mesh=mesh, batch=2)
 
         rng = np.random.default_rng(7)

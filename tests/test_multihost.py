@@ -36,12 +36,13 @@ from repro.hserve import (
 from repro.obs import MetricsRegistry, merge_snapshots
 from repro.runtime.failures import FailureInjector
 from repro.runtime.monitor import Heartbeat, StepMonitor
+from repro.launch.mesh import make_mesh
 
 PARAMS = small_params(logN=4, beta_bits=32)   # N=16, n_slots=8, L=5
 
 
 def _mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def _bitwise(a, b) -> bool:
@@ -224,6 +225,21 @@ def test_transport_kill_mid_batch_drops_computed_reply(keys, pool):
 # subprocess transport (a real process boundary)
 # --------------------------------------------------------------------------
 
+def test_subprocess_workers_need_an_explicit_platform(keys):
+    """A chip belongs to one process: the worker platform is never
+    defaulted, so a subprocess frontend without one is refused before
+    any process starts."""
+    _, _, evk = keys
+    with pytest.raises(ValueError, match="worker_platform"):
+        HEFrontend(PARAMS, evk, transport="subprocess", workers=1)
+
+
+def test_parent_on_cpu_does_not_count_as_holding_a_chip():
+    from repro.hserve.transport import _parent_holds_accelerator
+    assert jax.devices()[0].platform == "cpu"
+    assert not _parent_holds_accelerator()
+
+
 def test_subprocess_workers_serve_bitwise(keys, pool, reference):
     """One spawned worker process, frames over stdin/stdout: the same
     stream (muls at two levels + a rotate through an init-shipped key)
@@ -233,6 +249,7 @@ def test_subprocess_workers_serve_bitwise(keys, pool, reference):
     rk = {1: rot_keygen(PARAMS, sk, 1)}
     ref_srv = HEServer(PARAMS, evk, rot_keys=rk, mesh=_mesh(), batch=2)
     fe = HEFrontend(PARAMS, evk, rot_keys=rk, transport="subprocess",
+                    worker_platform="cpu",
                     workers=1, batch=2)
     try:
         rids = _submit_stream(fe, top, lo, n_each=2)
@@ -268,6 +285,7 @@ def test_subprocess_worker_respawn_restores_full_strength(keys, pool):
     ref_res = ref_srv.drain()
 
     fe = HEFrontend(PARAMS, evk, rot_keys=rk, transport="subprocess",
+                    worker_platform="cpu",
                     workers=2, batch=2,
                     injector=FailureInjector(kill_worker_at={0: 1}))
     try:
@@ -318,7 +336,7 @@ def test_worker_death_requeue_on_8_device_mesh(run_in_8dev_subprocess):
 
         params = test_params(logN=5, beta_bits=32)
         sk, pk, evk = keygen(params, seed=0)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         rng = np.random.default_rng(0)
         n = params.n_slots_max
         pool = [H.encrypt_message(

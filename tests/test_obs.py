@@ -28,6 +28,7 @@ from repro.obs import MetricsRegistry, Reservoir, StageTimer, Tracer
 from repro.obs.report import analyze, format_report, load_events
 from repro.obs.trace import _NULL_SPAN
 from repro.runtime.monitor import Heartbeat, StepMonitor
+from repro.launch.mesh import make_mesh
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -426,7 +427,7 @@ def test_traced_profiled_serving_is_bitwise_with_full_lifecycle(keys):
     with schema-valid events, books Fig. 3 stage time for every staged
     op, and snapshots the whole stack through one registry."""
     _, pk, evk, rks = keys
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     tr = Tracer()
     srv = HEServer(PARAMS, evk, rks, mesh=mesh, batch=2,
                    tracer=tr, profile_stages=True)
@@ -464,7 +465,7 @@ def test_traced_profiled_serving_is_bitwise_with_full_lifecycle(keys):
 
 def test_trace_roundtrips_through_the_offline_report(tmp_path, keys):
     _, pk, evk, rks = keys
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     tr = Tracer()
     srv = HEServer(PARAMS, evk, rks, mesh=mesh, batch=2,
                    tracer=tr, profile_stages=True)
@@ -483,7 +484,7 @@ def test_trace_roundtrips_through_the_offline_report(tmp_path, keys):
 def test_session_publishes_client_counters(keys):
     from repro.client import HESession
     sk, pk, evk = keygen(PARAMS, seed=0)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     s = HESession(PARAMS, sk, pk, evk, mesh=mesh, batch=2)
     x = s.encrypt(0.5 * np.ones(8), seed=3)
     f = s.run([x * x])[0]
@@ -514,7 +515,7 @@ def test_traced_serving_on_8_device_mesh_records_all_phases(
         params = test_params(logN=5, beta_bits=32)
         sk, pk, evk = keygen(params, seed=0)
         rks = {1: rot_keygen(params, sk, 1)}
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         tr = Tracer()
         server = HEServer(params, evk, rks, mesh=mesh, batch=2,
                           tracer=tr, profile_stages=True)

@@ -14,6 +14,7 @@ from repro.core import make_context
 from repro.core import rns
 from repro.core.context import build_global_tables
 from repro.nt.residue import limbs_to_int
+from repro.launch.mesh import make_mesh
 
 
 PARAMS = small_params(logN=4, beta_bits=32)
@@ -124,7 +125,7 @@ def _fake_hserver(schedule: bool, batch: int):
         sk, pk, evk = keygen(PARAMS, seed=0)
         _fake_hserver._keys = (sk, pk, evk, conj_keygen(PARAMS, sk))
     sk, pk, evk, ck = _fake_hserver._keys
-    mesh = _jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     server = HEServer(PARAMS, evk, None, ck, mesh=mesh, batch=batch,
                       schedule=schedule, prefetch=False)
 
@@ -279,7 +280,7 @@ def trace_session():
     from repro.client import HESession
     from repro.core.rotate import conj_keygen, rot_keygen
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     s = HESession(TRACE_PARAMS, seed=0, mesh=mesh, batch=2)
     rks = {r: rot_keygen(TRACE_PARAMS, s.sk, r) for r in (1, 2, 4)}
     return s, rks, conj_keygen(TRACE_PARAMS, s.sk)
@@ -341,7 +342,7 @@ def _fake_frontend(workers, batch, schedule, injector, log):
         sk, pk, evk = keygen(PARAMS, seed=0)
         _fake_hserver._keys = (sk, pk, evk, conj_keygen(PARAMS, sk))
     sk, pk, evk, ck = _fake_hserver._keys
-    mesh = _jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     fe = HEFrontend(PARAMS, evk, None, ck, mesh=mesh, batch=batch,
                     workers=workers, schedule=schedule,
                     injector=injector)
@@ -483,7 +484,7 @@ def mh_trace_session():
     from repro.core.rotate import conj_keygen, rot_keygen
     from repro.hserve.frontend import HEFrontend
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     sk, pk, evk = keygen(TRACE_PARAMS, seed=0)
     fe = HEFrontend(TRACE_PARAMS, evk, mesh=mesh, batch=2, workers=2)
     s = HESession(TRACE_PARAMS, sk=sk, pk=pk, evk=evk, server=fe)

@@ -27,6 +27,7 @@ from repro.analysis.manifest import (
 )
 from repro.analysis.rules import RULES
 from repro.analysis.xla import DEFAULT_HBM_BUDGET, check_cell
+from repro.launch.mesh import make_mesh
 
 REPO = Path(__file__).resolve().parents[1]
 
@@ -234,7 +235,7 @@ def test_measure_cell_single_device_record_and_clean_check():
     from repro.core.params import test_params
 
     params = test_params(logN=4, beta_bits=32)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     cell = measure_cell("mul", params.logQ, mesh, params, 2)
     # one device: nothing on the wire, predicted and measured alike
     assert cell["collectives"]["counts"] == {}
@@ -291,9 +292,9 @@ def test_shardlint_cli_clean_and_injected_on_8_device_mesh(
     assert res["rc_ok"] == 0 and res["errors_ok"] == 0
     assert res["cells_ok"] == ["add/120/2x4", "mul/120/2x4",
                                "rotate/120/2x4"]
-    # mul at full depth: (3 + 2) iCRT reductions x 3 all-reduces each,
-    # and the measured ring-model bytes equal the analytic prediction
-    assert res["ar_mul"] == 15
+    # mul at full depth: (3 + 2) iCRT reductions x 5 all-reduced tensors
+    # each, and the measured ring-model bytes equal the analytic prediction
+    assert res["ar_mul"] == 25
     assert res["bytes_match"]
     assert res["rc_bad"] == 1 and res["errors_bad"] >= 2
     assert "HS101" in res["rules_bad"]
