@@ -41,9 +41,8 @@ iCRT entries. See ``IcrtTables.quot_fix`` in `core/context.py` and
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
-from typing import Callable, Dict, Optional, Tuple
+from typing import Callable, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -342,10 +341,6 @@ class StageFns:
     ev: Callable[[jnp.ndarray], jnp.ndarray]       # eval-domain placement
     out: Callable[[jnp.ndarray], jnp.ndarray]      # output placement
     modified_shoup: bool
-    # Fig. 3 attribution hook (repro.obs.StageTimer); None on the fused
-    # jit path — timers cannot run under tracing, so the engine only
-    # passes one when steps execute eagerly (--profile-stages).
-    timer: Optional[object] = None
 
 
 def make_stage_fns(st: HEStatic, mesh: Mesh, *,
@@ -353,24 +348,18 @@ def make_stage_fns(st: HEStatic, mesh: Mesh, *,
                    icrt_strategy: str = "sum16",
                    modified_shoup: bool = False,
                    reduce_scatter_icrt: bool = False,
-                   use_kernels: bool = False,
-                   stage_timer=None) -> StageFns:
+                   use_kernels: bool = False) -> StageFns:
     """Bind strategy knobs + mesh placements into a reusable stage bundle.
 
     `use_kernels` routes CRT/NTT/iNTT/iCRT/pointwise through the
     repro.kernels Pallas paths (β = 2^32 only; interpret mode off-TPU).
 
-    `stage_timer` (a `repro.obs.StageTimer`) fences and clocks every
-    stage call in the paper's Fig. 3 taxonomy — crt, ntt (fwd + inv),
-    modmul (Montgomery and Shoup pointwise), icrt. Only legal on steps
-    that are NOT jitted as a whole: the fence is a host-side
-    block_until_ready, meaningless (and rejected by jax) under tracing.
-    To keep the attribution honest, profiling jits each stage as its
-    own BLOCK (compiled once per shape, fenced after each call) — fully
-    eager execution would bury the real stage compute under
-    per-primitive dispatch overhead that belongs to no stage. The math
-    is identical either way, so the timed path stays
-    bitwise-identical.
+    Every stage call runs under a `jax.named_scope` of the paper's
+    Fig. 3 taxonomy — ``he.crt``, ``he.ntt``, ``he.intt``, ``he.modmul``
+    (Montgomery and Shoup pointwise) and ``he.icrt`` — so the device ops
+    of the fused step carry their stage in their `op_name` metadata and
+    a profiler trace splits the step's device time by stage. Scopes are
+    metadata only: the compiled program and its results are unchanged.
     """
     if use_kernels:
         assert st.params.beta_bits == 32, \
@@ -392,111 +381,31 @@ def make_stage_fns(st: HEStatic, mesh: Mesh, *,
     def out(x):
         return jax.lax.with_sharding_constraint(x, out_sh)
 
-    if stage_timer is None:
-        def timed(stage, thunk):
-            return thunk()
+    def to_eval(x, t):
+        with jax.named_scope("he.crt"):
+            r = _crt_b(x, t, crt_strategy, use_kernels)
+        with jax.named_scope("he.ntt"):
+            return ev(_ntt_b(ev(r), t, modified_shoup, use_kernels))
 
-        def crt_f(x, t):
-            return _crt_b(x, t, crt_strategy, use_kernels)
-
-        def ntt_f(r, t):
-            return _ntt_b(r, t, modified_shoup, use_kernels)
-
-        def intt_f(r, t):
-            return _intt_b(r, t, modified_shoup, use_kernels)
-
-        def mont_f(a, b, t):
-            return _mont_mul_b(a, b, t, use_kernels)
-
-        def shoup_f(e, w, ws, p):
-            return pointwise_shoup_scale(e, w, ws, p,
-                                         modified=modified_shoup)
-
-        def icrt_f(r, t, tabs, out_limbs):
-            return _icrt_b(r, t, tabs, out_limbs, icrt_strategy,
-                           use_kernels)
-    else:
-        # profiling: each stage compiles as its own block, so a timed
-        # call measures the stage's fused compute, not uncompiled
-        # per-primitive dispatch. One jit per stage per shape signature.
-        # The inter-stage mesh placements fold INTO the neighbouring
-        # stage's block (they are free data-layout hints under jit, but
-        # standalone eager dispatches that would inflate the un-bucketed
-        # remainder and erode the coverage gate if left outside).
-        timed = stage_timer.timed
-        crt_f = jax.jit(
-            lambda x, t: _crt_b(x, t, crt_strategy, use_kernels))
-        ntt_f = jax.jit(
-            lambda r, t: ev(_ntt_b(ev(r), t, modified_shoup,
-                                   use_kernels)))
-        intt_f = jax.jit(
-            lambda r, t: _intt_b(r, t, modified_shoup, use_kernels))
-        mont_f = jax.jit(
-            lambda a, b, t: _mont_mul_b(a, b, t, use_kernels))
-        shoup_f = jax.jit(
-            lambda e, w, ws, p: pointwise_shoup_scale(
-                e, w, ws, p, modified=modified_shoup))
-        _icrt_jits: Dict[Tuple[int, int], Callable] = {}
-
-        def icrt_f(r, t, tabs, out_limbs):
-            # tabs is host-side static table metadata (baked into the
-            # trace exactly as the fused path bakes it via closure)
-            key = (id(tabs), out_limbs)
-            if key not in _icrt_jits:
-                _icrt_jits[key] = jax.jit(lambda rr, tt: limbs(_icrt_b(
-                    ev(rr), tt, tabs, out_limbs, icrt_strategy,
-                    use_kernels)))
-            return _icrt_jits[key](r, t)
-
-    if stage_timer is None:
-        def to_eval(x, t):
-            r = timed("crt", lambda: crt_f(x, t))
-            return ev(timed("ntt", lambda: ntt_f(ev(r), t)))
-
-        def from_eval(e, t, tabs, out_limbs):
-            # iNTT books under "ntt": Fig. 3 plots one transform bucket.
-            res = timed("ntt", lambda: intt_f(e, t))
-            return limbs(timed("icrt", lambda: icrt_f(ev(res), t, tabs,
-                                                      out_limbs)))
-    else:
-        # placements already live inside the jitted stage blocks
-        def to_eval(x, t):
-            r = timed("crt", lambda: crt_f(x, t))
-            return timed("ntt", lambda: ntt_f(r, t))
-
-        def from_eval(e, t, tabs, out_limbs):
-            res = timed("ntt", lambda: intt_f(e, t))
-            return timed("icrt", lambda: icrt_f(res, t, tabs, out_limbs))
+    def from_eval(e, t, tabs, out_limbs):
+        with jax.named_scope("he.intt"):
+            res = _intt_b(e, t, modified_shoup, use_kernels)
+        with jax.named_scope("he.icrt"):
+            return limbs(_icrt_b(ev(res), t, tabs, out_limbs,
+                                 icrt_strategy, use_kernels))
 
     def mont_mul(a, b, t):
-        return timed("modmul", lambda: mont_f(a, b, t))
+        with jax.named_scope("he.modmul"):
+            return _mont_mul_b(a, b, t, use_kernels)
 
     def shoup_mul(e, w, w_shoup, primes):
-        return timed("modmul", lambda: shoup_f(e, w, w_shoup, primes))
-
-    if stage_timer is not None:
-        # output placement too — the last eager dispatch on the path
-        out = jax.jit(out)
+        with jax.named_scope("he.modmul"):
+            return pointwise_shoup_scale(e, w, w_shoup, primes,
+                                         modified=modified_shoup)
 
     return StageFns(to_eval=to_eval, from_eval=from_eval,
                     mont_mul=mont_mul, shoup_mul=shoup_mul, ev=ev, out=out,
-                    modified_shoup=modified_shoup, timer=stage_timer)
-
-
-def _region(sf: StageFns, name: str):
-    """Fig. 2 region scope when the bundle carries a StageTimer; free
-    (nullcontext) on the fused path."""
-    return sf.timer.region(name) if sf.timer is not None \
-        else contextlib.nullcontext()
-
-
-def _glue_jit(sf: StageFns):
-    """jax.jit for the un-bucketed glue (BigInt shifts/adds, masks,
-    automorphism permutes) when profiling — uncompiled glue would
-    dominate the device wall with dispatch overhead that belongs to no
-    Fig. 3 stage and sink the stage-coverage contract. Identity on the
-    fused path (the enclosing step jit owns everything)."""
-    return jax.jit if sf.timer is not None else (lambda f: f)
+                    modified_shoup=modified_shoup)
 
 
 def make_keyswitch_step(st: HEStatic, sf: StageFns):
@@ -509,11 +418,12 @@ def make_keyswitch_step(st: HEStatic, sf: StageFns):
     """
     np2, ks_limbs = st.np2, st.ks_limbs
     logQ, qlimbs = st.params.logQ, st.qlimbs
-    shift_f = _glue_jit(sf)(
-        lambda x: bigint.shift_right_round(x, logQ, out_limbs=qlimbs))
+
+    def shift_f(x):
+        return bigint.shift_right_round(x, logQ, out_limbs=qlimbs)
 
     def ks(t2, ek, d):
-        with _region(sf, "region2"):
+        with jax.named_scope("he.region2"):
             e2 = sf.to_eval(d, t2)
             p2 = t2["primes"]
             ks_ax = sf.from_eval(
@@ -536,8 +446,7 @@ def make_he_mul_step(st: HEStatic, mesh: Mesh, *,
                      icrt_strategy: str = "sum16",
                      modified_shoup: bool = False,
                      reduce_scatter_icrt: bool = False,
-                     use_kernels: bool = False,
-                     stage_timer=None):
+                     use_kernels: bool = False):
     """Build step(t1, t2, ek, ax1, bx1, ax2, bx2) -> (ax3, bx3).
 
     Operands are (B, N, qlimbs) limb batches; outputs likewise. Strategy
@@ -553,19 +462,16 @@ def make_he_mul_step(st: HEStatic, mesh: Mesh, *,
                         icrt_strategy=icrt_strategy,
                         modified_shoup=modified_shoup,
                         reduce_scatter_icrt=reduce_scatter_icrt,
-                        use_kernels=use_kernels,
-                        stage_timer=stage_timer)
+                        use_kernels=use_kernels)
     keyswitch = make_keyswitch_step(st, sf)
-    gj = _glue_jit(sf)
-    add_f = gj(lambda a, b, p: modadd(a, b, p))
-    d1fix_f = gj(lambda d1, d0, d2, p: modsub(modsub(d1, d0, p), d2, p))
-    mask_f = gj(lambda x: bigint.mask_bits(x, logq))
-    comb_f = gj(lambda d, ks: bigint.mask_bits(bigint.add(d, ks), logq))
+
+    def comb_f(d, ks):
+        return bigint.mask_bits(bigint.add(d, ks), logq)
 
     def step(t1, t2, ek, ax1, bx1, ax2, bx2):
         p1 = t1["primes"][:, None]
         # ---- region 1: 4×(CRT→NTT), 3 pointwise, 3×(iNTT→iCRT) ----------
-        with _region(sf, "region1"):
+        with jax.named_scope("he.region1"):
             ea1 = sf.to_eval(ax1, t1)
             eb1 = sf.to_eval(bx1, t1)
             ea2 = sf.to_eval(ax2, t1)
@@ -573,13 +479,14 @@ def make_he_mul_step(st: HEStatic, mesh: Mesh, *,
 
             d0_ev = sf.mont_mul(eb1, eb2, t1)
             d2_ev = sf.mont_mul(ea1, ea2, t1)
-            d1_ev = sf.mont_mul(add_f(ea1, eb1, p1),
-                                add_f(ea2, eb2, p1), t1)
-            d1_ev = d1fix_f(d1_ev, d0_ev, d2_ev, p1)
+            d1_ev = sf.mont_mul(modadd(ea1, eb1, p1),
+                                modadd(ea2, eb2, p1), t1)
+            d1_ev = modsub(modsub(d1_ev, d0_ev, p1), d2_ev, p1)
 
             d0 = sf.from_eval(d0_ev, t1, st.icrt1, qlimbs)
             d1 = sf.from_eval(d1_ev, t1, st.icrt1, qlimbs)
-            d2 = mask_f(sf.from_eval(d2_ev, t1, st.icrt1, qlimbs))
+            d2 = bigint.mask_bits(sf.from_eval(d2_ev, t1, st.icrt1, qlimbs),
+                                  logq)
 
         # ---- region 2: key switching against the evk --------------------
         ks_ax, ks_bx = keyswitch(t2, ek, d2)

@@ -53,7 +53,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Callable, Dict, List, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -64,27 +64,20 @@ from repro.core.heaan import mod_down_poly, mod_raise_poly, rescale_poly
 from repro.core.params import HEParams
 from repro.core.rotate import automorphism_poly, conjugation_k, rotation_k
 from repro.dist.he_pipeline import (
-    HEStatic, _glue_jit, he_static, make_he_mul_step, make_keyswitch_step,
+    HEStatic, he_static, make_he_mul_step, make_keyswitch_step,
     make_stage_fns,
 )
 from repro.dist.sharding import he_limb_sharding
 from repro.hserve.queue import Batch
 from repro.hserve.tables import TableCache
-from repro.obs.stages import StageTimer
+from repro.obs.registry import Counter
+from repro.obs.trace import span
 
-__all__ = ["STAGE_OPS", "slot_sum_rotations", "make_he_rotate_step",
+__all__ = ["slot_sum_rotations", "make_he_rotate_step",
            "make_slot_sum_step", "make_rescale_step", "make_mod_down_step",
            "make_mod_raise_step", "make_addsub_step", "make_mul_plain_step",
            "make_add_plain_step",
            "Inflight", "OpEngine"]
-
-
-# Ops whose steps run the Fig. 3 stage chain (CRT/NTT/modmul/iCRT) and
-# therefore must execute stage-by-stage under --profile-stages. The
-# rest (limb shifts/slices/adds) have no stages to attribute and stay
-# fully jitted even while profiling.
-STAGE_OPS = frozenset(
-    {"mul", "rotate", "conjugate", "slot_sum", "mul_plain"})
 
 
 def slot_sum_rotations(n_slots: int) -> Tuple[int, ...]:
@@ -120,18 +113,15 @@ def make_he_rotate_step(st: HEStatic, mesh, k: int, **knobs):
     """
     sf = make_stage_fns(st, mesh, **knobs)
     keyswitch = make_keyswitch_step(st, sf)
-    gj = _glue_jit(sf)
-    auto_b = gj(_make_automorphism_b(st, k))
+    auto_b = _make_automorphism_b(st, k)
     logq = st.logq
-    mask_f = gj(lambda x: bigint.mask_bits(x, logq))
-    addmask_f = gj(lambda a, b: bigint.mask_bits(bigint.add(a, b), logq))
 
     def step(t2, rk, ax, bx):
         ax_r = auto_b(ax)
         bx_r = auto_b(bx)
         ks_ax, ks_bx = keyswitch(t2, rk, ax_r)
-        ax3 = mask_f(ks_ax)
-        bx3 = addmask_f(bx_r, ks_bx)
+        ax3 = bigint.mask_bits(ks_ax, logq)
+        bx3 = bigint.mask_bits(bigint.add(bx_r, ks_bx), logq)
         return sf.out(ax3), sf.out(bx3)
 
     return step
@@ -144,20 +134,20 @@ def make_slot_sum_step(st: HEStatic, mesh, n_slots: int, **knobs):
     key pytrees in slot_sum_rotations(n_slots) order."""
     sf = make_stage_fns(st, mesh, **knobs)
     keyswitch = make_keyswitch_step(st, sf)
-    gj = _glue_jit(sf)
     params = st.params
-    autos = [gj(_make_automorphism_b(st, rotation_k(params, r)))
+    autos = [_make_automorphism_b(st, rotation_k(params, r))
              for r in slot_sum_rotations(n_slots)]
     logq = st.logq
-    mask_f = gj(lambda x: bigint.mask_bits(x, logq))
-    addmask_f = gj(lambda a, b: bigint.mask_bits(bigint.add(a, b), logq))
+
+    def addmask_f(a, b):
+        return bigint.mask_bits(bigint.add(a, b), logq)
 
     def step(t2, rks, ax, bx):
         for auto_b, rk in zip(autos, rks):
             ax_r = auto_b(ax)
             bx_r = auto_b(bx)
             ks_ax, ks_bx = keyswitch(t2, rk, ax_r)
-            rot_ax = mask_f(ks_ax)
+            rot_ax = bigint.mask_bits(ks_ax, logq)
             rot_bx = addmask_f(bx_r, ks_bx)
             ax = addmask_f(ax, rot_ax)
             bx = addmask_f(bx, rot_bx)
@@ -248,7 +238,9 @@ def make_mul_plain_step(st: HEStatic, mesh, **knobs):
     """
     sf = make_stage_fns(st, mesh, **knobs)
     logq, qlimbs = st.logq, st.qlimbs
-    mask_f = _glue_jit(sf)(lambda x: bigint.mask_bits(x, logq))
+
+    def mask_f(x):
+        return bigint.mask_bits(x, logq)
 
     def step(t1, ax, bx, pt):
         ept = sf.to_eval(pt, t1)
@@ -287,6 +279,7 @@ class Inflight:
     ax: jnp.ndarray
     bx: jnp.ndarray
     t0: float
+    wall: Optional[float] = None     # dispatch→ready, once blocked on
 
 
 class OpEngine:
@@ -295,61 +288,36 @@ class OpEngine:
     Steps are cached by batch bucket key; tables come from the level-aware
     TableCache, so a new level costs one trace + slice views, never a
     table rebuild. `dispatch` places operands on the mesh's data axis and
-    launches the step asynchronously; `wait` blocks, re-wraps the valid
-    rows as Ciphertexts with the op's output level metadata, and returns
-    the measured device wall time. `run` = wait(dispatch(batch)).
+    launches the step asynchronously; `block` waits for it and returns
+    the measured device wall time; `wait` blocks (once) and re-wraps the
+    valid rows as Ciphertexts with the op's output level metadata. `run`
+    = wait(dispatch(batch)).
+
+    registry: the `repro.obs.MetricsRegistry` that holds the engine's
+        ``engine.h2d_bytes`` counter (bytes handed to `device_put`);
+        without one the counter is private to the engine.
     """
 
     def __init__(self, params: HEParams, mesh, cache: TableCache, *,
                  use_kernels: bool = False, crt_strategy: str = "acc3",
                  icrt_strategy: str = "sum16",
-                 modified_shoup: bool = False, tracer=None,
-                 profile_stages: bool = False):
+                 modified_shoup: bool = False, tracer=None, registry=None):
         self.params = params
         self.mesh = mesh
         self.cache = cache
-        self.profile_stages = profile_stages
-        # Fig. 3 attribution (repro.obs.StageTimer) needs per-stage
-        # host-side fences, which jit tracing cannot express — so
-        # profiling swaps jit for eager execution (same math, same
-        # bits, slower) and threads the timer through make_stage_fns.
-        self.stage_timer = StageTimer(tracer=tracer) if profile_stages \
-            else None
-        self._tracer = tracer
+        self.tracer = tracer
+        self.h2d_bytes = registry.counter("engine.h2d_bytes") \
+            if registry is not None else Counter()
         self._knobs = dict(use_kernels=use_kernels,
                            crt_strategy=crt_strategy,
                            icrt_strategy=icrt_strategy,
                            modified_shoup=modified_shoup)
-        if profile_stages:
-            self._knobs["stage_timer"] = self.stage_timer
         self._steps: Dict[Tuple, Callable] = {}
         self._static: Dict[int, HEStatic] = {}
         self._warmed: set = set()
         self.compile_s = 0.0
         # warm (trace + compile + first run) seconds per bucket key
         self.compile_s_by_key: Dict[Tuple, float] = {}
-
-    @property
-    def tracer(self):
-        return self._tracer
-
-    @tracer.setter
-    def tracer(self, t) -> None:
-        """Re-pointable post-construction (benchmarks toggle tracing on
-        a warm server); the stage timer follows the engine's tracer."""
-        self._tracer = t
-        if self.stage_timer is not None:
-            self.stage_timer.tracer = t
-
-    def _jit(self, fn: Callable, op: str) -> Callable:
-        """jax.jit normally; identity under --profile-stages for ops in
-        STAGE_OPS, whose stage fences must observe each stage's device
-        completion (the stage/glue blocks inside are jitted
-        individually). Stage-less limb ops have nothing to attribute
-        and keep the fused jit either way."""
-        if self.profile_stages and op in STAGE_OPS:
-            return fn
-        return jax.jit(fn)
 
     def _st(self, logq: int) -> HEStatic:
         if logq not in self._static:
@@ -365,8 +333,7 @@ class OpEngine:
         st = self._st(logq)
         t1, t2 = self.cache.level_tables(logq)
         if op == "mul":
-            step = self._jit(make_he_mul_step(st, self.mesh, **self._knobs),
-                             op)
+            step = jax.jit(make_he_mul_step(st, self.mesh, **self._knobs))
             ek = self.cache.evk()
 
             def runner(a):
@@ -374,65 +341,61 @@ class OpEngine:
                             a["ax2"], a["bx2"])
         elif op == "rotate":
             k = rotation_k(self.params, extra)
-            step = self._jit(
-                make_he_rotate_step(st, self.mesh, k, **self._knobs), op)
+            step = jax.jit(
+                make_he_rotate_step(st, self.mesh, k, **self._knobs))
             rk = self.cache.rot_key(extra)
 
             def runner(a):
                 return step(t2, rk, a["ax1"], a["bx1"])
         elif op == "conjugate":
-            step = self._jit(make_he_rotate_step(
+            step = jax.jit(make_he_rotate_step(
                 st, self.mesh, conjugation_k(self.params),
-                **self._knobs), op)
+                **self._knobs))
             ck = self.cache.conj_key()
 
             def runner(a):
                 return step(t2, ck, a["ax1"], a["bx1"])
         elif op == "slot_sum":
-            step = self._jit(
-                make_slot_sum_step(st, self.mesh, extra, **self._knobs),
-                op)
+            step = jax.jit(
+                make_slot_sum_step(st, self.mesh, extra, **self._knobs))
             rks = tuple(self.cache.rot_key(r)
                         for r in slot_sum_rotations(extra))
 
             def runner(a):
                 return step(t2, rks, a["ax1"], a["bx1"])
         elif op == "rescale":
-            step = self._jit(
-                make_rescale_step(st, self.mesh, extra, **self._knobs),
-                op)
+            step = jax.jit(
+                make_rescale_step(st, self.mesh, extra, **self._knobs))
 
             def runner(a):
                 return step(a["ax1"], a["bx1"])
         elif op == "mod_down":
-            step = self._jit(
-                make_mod_down_step(st, self.mesh, extra, **self._knobs),
-                op)
+            step = jax.jit(
+                make_mod_down_step(st, self.mesh, extra, **self._knobs))
 
             def runner(a):
                 return step(a["ax1"], a["bx1"])
         elif op == "mod_raise":
-            step = self._jit(
-                make_mod_raise_step(st, self.mesh, extra, **self._knobs),
-                op)
+            step = jax.jit(
+                make_mod_raise_step(st, self.mesh, extra, **self._knobs))
 
             def runner(a):
                 return step(a["ax1"], a["bx1"])
         elif op in ("add", "sub"):
-            step = self._jit(
-                make_addsub_step(st, self.mesh, op, **self._knobs), op)
+            step = jax.jit(
+                make_addsub_step(st, self.mesh, op, **self._knobs))
 
             def runner(a):
                 return step(a["ax1"], a["bx1"], a["ax2"], a["bx2"])
         elif op == "mul_plain":
-            step = self._jit(
-                make_mul_plain_step(st, self.mesh, **self._knobs), op)
+            step = jax.jit(
+                make_mul_plain_step(st, self.mesh, **self._knobs))
 
             def runner(a):
                 return step(t1, a["ax1"], a["bx1"], a["pt"])
         elif op == "add_plain":
-            step = self._jit(
-                make_add_plain_step(st, self.mesh, **self._knobs), op)
+            step = jax.jit(
+                make_add_plain_step(st, self.mesh, **self._knobs))
 
             def runner(a):
                 return step(a["ax1"], a["bx1"], a["pt"])
@@ -447,15 +410,14 @@ class OpEngine:
 
     def _place(self, batch: Batch) -> Dict[str, jnp.ndarray]:
         sh = he_limb_sharding(self.mesh, batch=batch.size)
-        if self._tracer is None:
-            return {k: jax.device_put(v, sh)
-                    for k, v in batch.arrays.items()}
+        nbytes = sum(v.nbytes for v in batch.arrays.values())
+        self.h2d_bytes.inc(nbytes)
         # H2D span: device_put is async, so this measures enqueue — on
         # the overlap path that is exactly the host-side transfer work
         # hidden behind the in-flight batch.
-        with self._tracer.span("h2d", cat="engine", lane="engine",
-                               args={"op": batch.op,
-                                     "batch": batch.size}):
+        with span(self.tracer, "h2d", cat="engine", lane="engine",
+                  args=None if self.tracer is None else
+                  {"op": batch.op, "batch": batch.size, "bytes": nbytes}):
             return {k: jax.device_put(v, sh)
                     for k, v in batch.arrays.items()}
 
@@ -475,23 +437,14 @@ class OpEngine:
         if batch.key in self._warmed:
             return
         runner = self._step_for(batch.key)
-        span = self._tracer.span(
-            "warm_compile", cat="engine", lane="engine",
-            args={"op": batch.op, "logq": batch.logq}) \
-            if self._tracer is not None else None
         t0 = time.perf_counter()
-        if self.stage_timer is not None:
-            # warm runs must not pollute the Fig. 3 attribution: the
-            # coverage gate compares stage sums against METERED wall.
-            with self.stage_timer.pause():
-                jax.block_until_ready(runner(self._place(batch)))
-        else:
+        with span(self.tracer, "warm_compile", cat="engine", lane="engine",
+                  args=None if self.tracer is None else
+                  {"op": batch.op, "logq": batch.logq}):
             jax.block_until_ready(runner(self._place(batch)))
         elapsed = time.perf_counter() - t0
         self.compile_s += elapsed
         self.compile_s_by_key[batch.key] = elapsed
-        if span is not None:
-            span.end()
         self._warmed.add(batch.key)
 
     # ---- async execution (double buffering) ------------------------------
@@ -508,34 +461,40 @@ class OpEngine:
         runner = self._step_for(batch.key)
         arrays = self._place(batch)
         t0 = time.perf_counter()
-        if self.stage_timer is not None:
-            with self.stage_timer.op(batch.op):
-                ax, bx = runner(arrays)
-        else:
+        with span(self.tracer, "launch", cat="engine", lane="engine"):
             ax, bx = runner(arrays)
         return Inflight(batch=batch, ax=ax, bx=bx, t0=t0)
 
-    def wait(self, inflight: Inflight
-             ) -> Tuple[List[Ciphertext], float]:
-        """Block on a dispatched batch; returns (outputs, wall_s) with
-        the n_valid outputs in request order (padded lanes computed and
-        discarded) and the dispatch→ready wall time AS OBSERVED BY THE
-        HOST. On the synchronous run() path that is the device wall; on
-        the overlapped path it additionally includes any host time
-        between dispatch and this wait (an upper bound on device time —
-        HEServer.poll retires an idle in-flight batch eagerly, so the
-        slack is bounded by the caller's poll cadence). Per-op ops_per_s
-        under overlap is therefore host-observed; use drain wall clocks
-        (benchmarks/serve_he.py "overlap") to quantify the overlap win."""
-        jax.block_until_ready((inflight.ax, inflight.bx))
-        wall = time.perf_counter() - inflight.t0
-        if self._tracer is not None:
+    def block(self, inflight: Inflight) -> float:
+        """Block on a dispatched batch (once: later calls return the
+        same reading); returns the dispatch→ready wall time AS OBSERVED
+        BY THE HOST. On the synchronous run() path that is the device
+        wall; on the overlapped path it additionally includes any host
+        time between dispatch and this call (an upper bound on device
+        time — HEServer.poll retires an idle in-flight batch eagerly, so
+        the slack is bounded by the caller's poll cadence). Per-op
+        ops_per_s under overlap is therefore host-observed; use drain
+        wall clocks (benchmarks/serve_he.py "overlap") to quantify the
+        overlap win."""
+        if inflight.wall is not None:
+            return inflight.wall
+        with span(self.tracer, "wait", cat="engine", lane="engine"):
+            jax.block_until_ready((inflight.ax, inflight.bx))
+        wall = inflight.wall = time.perf_counter() - inflight.t0
+        if self.tracer is not None:
             b = inflight.batch
-            self._tracer.event(
+            self.tracer.event(
                 "device_wall", cat="lifecycle", lane="engine",
                 ts=inflight.t0, dur=wall,
                 args={"op": b.op, "logq": b.logq, "batch": b.size,
                       "n_valid": b.n_valid})
+        return wall
+
+    def wait(self, inflight: Inflight
+             ) -> Tuple[List[Ciphertext], float]:
+        """`block`, then the n_valid outputs in request order (padded
+        lanes computed and discarded): (outputs, wall_s)."""
+        wall = self.block(inflight)
         return self._wrap(inflight.batch, inflight.ax, inflight.bx), wall
 
     def run(self, batch: Batch) -> List[Ciphertext]:

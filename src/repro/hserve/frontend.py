@@ -233,8 +233,7 @@ class HEFrontend(HEServer):
 
     Unsupported vs the monolith: `overlap` (the per-worker pipeline IS
     the overlap — every worker holds one in-flight batch while the
-    frontend assembles the next) and `profile_stages` (a worker-local
-    measurement mode; run it on a single HEServer).
+    frontend assembles the next).
     """
 
     def __init__(self, params: HEParams, evk: Optional[EvalKey] = None,

@@ -75,6 +75,7 @@ from repro.hserve.queue import Batch, BatchAssembler, PLAIN_OPS, \
 from repro.hserve.scheduler import CircuitScheduler
 from repro.hserve.tables import TableCache
 from repro.obs.registry import MetricsRegistry
+from repro.obs.trace import span
 
 __all__ = ["HEServer"]
 
@@ -140,10 +141,6 @@ class HEServer:
             per request. Mutable via the `tracer` property (propagates
             to the engine and table cache), so benchmarks toggle it on
             a warm server.
-    profile_stages: run engine steps EAGERLY with per-stage device
-            fences so `engine.stage_timer` attributes mul wall to the
-            paper's Fig. 3 CRT/NTT/modmul/iCRT buckets. Same bits,
-            slower — a measurement mode, not a serving mode.
     registry: optional `repro.obs.MetricsRegistry` to publish into
             (one is created when absent). ServeMetrics, TableCache,
             CircuitScheduler, and the engine register as pull sources;
@@ -168,18 +165,18 @@ class HEServer:
                  prefetch: bool = True,
                  plain_cache_mib: Optional[float] = 256.0,
                  clock: Callable[[], float] = time.perf_counter,
-                 tracer=None, profile_stages: bool = False,
-                 registry=None,
+                 tracer=None, registry=None,
                  **engine_knobs):
         if mesh is None:
             from repro.launch.mesh import make_host_mesh
             mesh = make_host_mesh()
+        if registry is None:
+            registry = MetricsRegistry()
         self.cache = TableCache(params, evk, rot_keys, conj_key,
                                 plain_cache_mib=plain_cache_mib)
         self.engine = OpEngine(params, mesh, self.cache,
                                use_kernels=use_kernels, tracer=tracer,
-                               profile_stages=profile_stages,
-                               **engine_knobs)
+                               registry=registry, **engine_knobs)
         self._init_core(params, mesh=mesh, batch=batch,
                         max_age_s=max_age_s,
                         adaptive_target=adaptive_target, overlap=overlap,
@@ -248,7 +245,7 @@ class HEServer:
     @tracer.setter
     def tracer(self, t) -> None:
         """Re-point the trace sink everywhere at once (engine + table
-        cache + the profile-mode stage timer follow the server's)."""
+        cache follow the server's)."""
         self._tracer = t
         if self.engine is not None:
             self.engine.tracer = t
@@ -544,6 +541,10 @@ class HEServer:
         progress guarantee), so a flush-poll on a non-empty queue can
         never return without running work.
         """
+        with span(self._tracer, "poll", cat="server", lane="server"):
+            return self._poll(flush)
+
+    def _poll(self, flush: bool) -> List[Tuple[int, Ciphertext]]:
         self._c_polls.inc()
         self._g_depth.set(self.queue.depth)
         self.metrics.record_depth(self.queue.depth)
@@ -558,18 +559,8 @@ class HEServer:
             self._prefetch_next(b)            # rides the in-flight step
             return self._retire(prev)
         inf = self._dispatch(b)
-        if self.engine.profile_stages:
-            # profiling dispatch is synchronous (fenced stage blocks):
-            # there is no in-flight step to hide the prefetch behind,
-            # and running it before wait() would book its host-side
-            # table-build time into this batch's device wall — sinking
-            # the Fig. 3 stage-coverage attribution.
-            outs, wall = self.engine.wait(inf)
-            self._prefetch_next(b)
-            return self._complete(b, outs, wall)
         self._prefetch_next(b)                # host work while b runs
-        outs, wall = self.engine.wait(inf)
-        return self._complete(b, outs, wall)
+        return self._retire(inf)
 
     def _choose_flush(self, flush: bool, now: float
                       ) -> Tuple[Optional[Tuple], str]:
@@ -617,10 +608,9 @@ class HEServer:
     def _dispatch(self, b: Batch) -> Inflight:
         """engine.dispatch under a "dispatch" lifecycle span (place +
         async launch; the device wall lands separately at wait)."""
-        if self._tracer is None:
-            return self.engine.dispatch(b)
-        with self._tracer.span("dispatch", cat="lifecycle", lane="server",
-                               args={"op": b.op, "batch": b.size}):
+        with span(self._tracer, "dispatch", cat="lifecycle", lane="server",
+                  args=None if self._tracer is None else
+                  {"op": b.op, "batch": b.size}):
             return self.engine.dispatch(b)
 
     def _prefetch_next(self, b: Batch) -> None:
@@ -632,11 +622,12 @@ class HEServer:
         running batch is the prefetch win."""
         if not (self.schedule and self.prefetch):
             return
-        tags = [t for t in (self._node_of_rid.get(r.rid)
-                            for r in b.requests) if t is not None]
-        levels = self.scheduler.next_levels(tags)
-        levels |= self.scheduler.levels_for_key(b.key)
-        self.scheduler.prefetch_levels(self.cache, levels)
+        with span(self._tracer, "prefetch", cat="server", lane="server"):
+            tags = [t for t in (self._node_of_rid.get(r.rid)
+                                for r in b.requests) if t is not None]
+            levels = self.scheduler.next_levels(tags)
+            levels |= self.scheduler.levels_for_key(b.key)
+            self.scheduler.prefetch_levels(self.cache, levels)
 
     def _take_inflight(self) -> Optional[Inflight]:
         inf, self._inflight = self._inflight, None
@@ -644,10 +635,14 @@ class HEServer:
 
     def _retire(self, inf: Optional[Inflight]
                 ) -> List[Tuple[int, Ciphertext]]:
+        """Block on a dispatched batch, then (under a "retire" span)
+        slice its outputs and account and route them."""
         if inf is None:
             return []
-        outs, wall = self.engine.wait(inf)
-        return self._complete(inf.batch, outs, wall)
+        self.engine.block(inf)
+        with span(self._tracer, "retire", cat="server", lane="server"):
+            outs, wall = self.engine.wait(inf)
+            return self._complete(inf.batch, outs, wall)
 
     def _complete(self, b: Batch, outs: List[Ciphertext], wall: float
                   ) -> List[Tuple[int, Ciphertext]]:
@@ -741,10 +736,8 @@ class HEServer:
         self.scheduler.reset_counters()
 
     def stats(self) -> dict:
-        st = self.engine.stage_timer
         return {
             **self.metrics.summary(),
-            **({"stages": st.summary()} if st is not None else {}),
             "cache": self.cache.stats(),
             "engine": {"steps_compiled": self.engine.n_compiled,
                        "compile_s": round(self.engine.compile_s, 3),

@@ -92,11 +92,11 @@ class WorkerEngine:
         self.mesh = mesh if mesh is not None else \
             make_mesh((1, 1), ("data", "model"))
         self.cache = TableCache(params, evk, rot_keys, conj_key)
+        self.registry = MetricsRegistry()
         self.engine = OpEngine(params, self.mesh, self.cache,
-                               **engine_knobs)
+                               registry=self.registry, **engine_knobs)
         self._clock = clock
         self.batches = 0
-        self.registry = MetricsRegistry()
         self._c_batches = self.registry.counter("worker.batches")
         self._c_requests = self.registry.counter("worker.requests")
         self._h_wall = self.registry.histogram("worker.batch.wall_s")

@@ -95,7 +95,7 @@ def serve_he(batch: int, requests: int = 0, levels: int = 1,
              overlap: bool = False, circuit: bool = False,
              schedule: bool = False, traced: int = 0,
              check: str = "off", seed: int = 0,
-             trace: str | None = None, profile_stages: bool = False,
+             trace: str | None = None, profile_dir: str | None = None,
              metrics: str | None = None, workers: int = 0,
              bootstrap: int = 0, params=None) -> dict:
     """Batched multi-level HE serving, driven through a `repro.client`
@@ -119,10 +119,12 @@ def serve_he(batch: int, requests: int = 0, levels: int = 1,
     Observability (repro.obs): `trace` writes a Chrome trace-event JSON
     of the request lifecycle + engine spans to that path (load it in
     Perfetto, or run `python -m repro.obs report PATH`);
-    `profile_stages` swaps stage-chain steps to the block-jitted eager
-    path (bitwise identical, slower) and prints the paper's Fig. 3
-    CRT/NTT/modmul/iCRT attribution; `metrics` dumps the registry
-    snapshot (serving telemetry plane) as JSON to that path.
+    `profile_dir` records a `jax.profiler` trace of the served stream
+    into that directory with the tracer on, so its ``hserve.*`` host
+    spans sit beside the device ops, which the ``he.*`` named scopes
+    assign to the paper's Fig. 3 stages (read it in xprof or Perfetto);
+    `metrics` dumps the registry snapshot (serving telemetry plane) as
+    JSON to that path.
 
     `workers` > 0 serves the same stream through the multi-host tier:
     an :class:`repro.hserve.HEFrontend` routing batches to that many
@@ -171,13 +173,13 @@ def serve_he(batch: int, requests: int = 0, levels: int = 1,
         raise ValueError(f"--levels must be in [1, {params.L - 1}]")
     if not 0.0 <= plain_frac <= 1.0:
         raise ValueError("--plain-frac must be in [0, 1]")
-    tracer = Tracer() if trace else None
+    tracer = Tracer() if trace or profile_dir else None
     if workers > 0:
-        if profile_stages or overlap:
+        if overlap:
             raise ValueError(
-                "--profile-stages/--overlap are single-server knobs; "
-                "the multi-host frontend pipelines across workers "
-                "instead of double-buffering one engine")
+                "--overlap is a single-server knob; the multi-host "
+                "frontend pipelines across workers instead of "
+                "double-buffering one engine")
         sk, pk, evk = keygen(params, seed=0)
         frontend = HEFrontend(
             params, evk, mesh=make_host_mesh(model=model_shards),
@@ -190,9 +192,10 @@ def serve_he(batch: int, requests: int = 0, levels: int = 1,
                             mesh=make_host_mesh(model=model_shards),
                             batch=batch, use_kernels=use_kernels,
                             max_age_s=max_age_s, overlap=overlap,
-                            schedule=schedule, tracer=tracer,
-                            profile_stages=profile_stages)
+                            schedule=schedule, tracer=tracer)
     server = session.server
+    if profile_dir:
+        jax.profiler.start_trace(profile_dir)
     if rotations:
         session.ensure_rotation_keys([1])
     if conjugations or circuit:
@@ -313,6 +316,8 @@ def serve_he(batch: int, requests: int = 0, levels: int = 1,
                 for rid, (op, _) in expect.items()
                 if op in ("mul", "mul_plain")}
     results.update(session.drain())
+    if profile_dir:
+        jax.profiler.stop_trace()
     for rid, rrid in rescaled.items():
         probes.setdefault(("rescale", results[rid].logq),
                           (rrid, (results[rid],)))
@@ -446,12 +451,13 @@ def main():
                          "flush → assemble → dispatch → device-wall → "
                          "complete) + engine spans; open in Perfetto or "
                          "run `python -m repro.obs report PATH`")
-    ap.add_argument("--profile-stages", action="store_true",
-                    help="attribute mul/rotate wall time to the paper's "
-                         "Fig. 3 stages (CRT/NTT/modmul/iCRT): stage-"
-                         "chain steps run as fenced block-jitted stages "
-                         "(bitwise identical, slower) and the per-stage "
-                         "split prints after the drain")
+    ap.add_argument("--profile-dir", default=None, metavar="DIR",
+                    help="record a jax.profiler trace of the served "
+                         "stream into DIR with the tracer on: hserve.* "
+                         "host spans beside the device ops, which the "
+                         "he.crt/ntt/intt/modmul/icrt scopes assign to "
+                         "the paper's Fig. 3 stages (open in xprof or "
+                         "Perfetto)")
     ap.add_argument("--workers", type=int, default=0,
                     help="serve through the multi-host tier: an "
                          "HEFrontend routing batches by (op, level) "
@@ -492,7 +498,7 @@ def main():
                          circuit=args.circuit, schedule=args.schedule,
                          traced=args.traced, check=args.check,
                          trace=args.trace,
-                         profile_stages=args.profile_stages,
+                         profile_dir=args.profile_dir,
                          metrics=args.metrics, workers=args.workers,
                          bootstrap=args.bootstrap,
                          params=CONFIG if args.full else None)
@@ -523,15 +529,8 @@ def main():
             print(f"  plaintext cache: {c['plain_hits']} hits / "
                   f"{c['plain_misses']} misses "
                   f"({c['plain_entries']} entries)")
-        if args.profile_stages:
-            for op, row in sorted(stats["stages"]["stages"].items()):
-                tot = sum(row.values())
-                wall = stats["per_op"].get(op, {}).get("wall_s", 0.0)
-                split = " ".join(
-                    f"{s} {1e3 * v:.1f}ms ({v / tot:.0%})"
-                    for s, v in row.items()) if tot else "—"
-                cov = f" coverage {tot / wall:.0%} of wall" if wall else ""
-                print(f"  fig3[{op}]: {split}{cov}")
+        if args.profile_dir:
+            print(f"  profiler trace -> {args.profile_dir}")
         if args.bootstrap:
             bs = stats["bootstrap"]
             print(f"  bootstrap: {bs['n']} concurrent pipeline(s) "

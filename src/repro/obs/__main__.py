@@ -12,8 +12,7 @@ from repro.obs.report import analyze, format_report, load_events
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
         prog="python -m repro.obs",
-        description="serving-trace analysis (Fig. 3 attribution + "
-                    "latency decomposition)")
+        description="serving-trace analysis (latency decomposition)")
     sub = ap.add_subparsers(dest="cmd", required=True)
     rep = sub.add_parser("report", help="summarize a trace.json written "
                                         "by serve --he --trace")

@@ -1,17 +1,13 @@
-"""Offline trace analysis: Fig. 3 attribution + latency decomposition.
+"""Offline trace analysis: the latency decomposition.
 
 `python -m repro.obs report trace.json` reads a Chrome trace-event file
-written by `serve --he --trace` and prints:
-
-  - per-op / per-stage attribution (cat="stage" events): wall seconds
-    in each of the paper's CRT / NTT / modmul / iCRT buckets, their
-    fraction of the op's bucketed total, and the Fig. 2 region split —
-    the table the paper's Fig. 3 is;
-  - a queue-wait vs device-wall latency decomposition (lifecycle
-    events): how much of each op's request latency is spent waiting in
-    a bucket (the batching/SLO trade) vs on the device (the compute
-    floor) — the serving-side split HEAX argues pipeline occupancy
-    from.
+written by `serve --he --trace` and prints a queue-wait vs device-wall
+latency decomposition (lifecycle events): how much of each op's request
+latency is spent waiting in a bucket (the batching/SLO trade) vs on the
+device (the compute floor) — the serving-side split HEAX argues
+pipeline occupancy from. The paper's Fig. 3 stage split lives in the
+profiler trace (`serve --he --profile-dir DIR`), where the ``he.*``
+named scopes label the fused step's device ops.
 
 Stdlib-only on purpose: the report runs anywhere the trace file lands,
 no jax/numpy needed.
@@ -22,8 +18,6 @@ from __future__ import annotations
 import json
 from collections import defaultdict
 from typing import Dict, List
-
-from repro.obs.stages import STAGES
 
 __all__ = ["load_events", "analyze", "format_report"]
 
@@ -36,11 +30,7 @@ def load_events(path: str) -> List[dict]:
 
 
 def analyze(events: List[dict]) -> dict:
-    """Aggregate a trace into the report's two tables (seconds)."""
-    stage_s: Dict[str, Dict[str, float]] = defaultdict(
-        lambda: {s: 0.0 for s in STAGES})
-    region_s: Dict[str, Dict[str, float]] = defaultdict(
-        lambda: defaultdict(float))
+    """Aggregate a trace into the report's table (seconds)."""
     wait_s: Dict[str, float] = defaultdict(float)
     wait_n: Dict[str, int] = defaultdict(int)
     dev_s: Dict[str, float] = defaultdict(float)
@@ -52,12 +42,7 @@ def analyze(events: List[dict]) -> dict:
         op = (e.get("args") or {}).get("op", "?")
         dur = e.get("dur", 0.0) / 1e6
         name = e.get("name")
-        if cat == "stage":
-            if name in STAGES:
-                stage_s[op][name] += dur
-            else:
-                region_s[op][name] += dur
-        elif cat == "lifecycle":
+        if cat == "lifecycle":
             if name == "bucket_wait":
                 wait_s[op] += dur
                 wait_n[op] += 1
@@ -69,8 +54,6 @@ def analyze(events: List[dict]) -> dict:
                 latency_s[op] += (e.get("args") or {}).get("latency_s",
                                                            0.0)
     return {
-        "stages": {op: dict(v) for op, v in stage_s.items()},
-        "regions": {op: dict(v) for op, v in region_s.items()},
         "queue_wait": {op: {"total_s": wait_s[op], "n": wait_n[op]}
                        for op in wait_n},
         "device_wall": {op: {"total_s": dev_s[op],
@@ -87,35 +70,9 @@ def _fmt_ms(s: float) -> str:
 
 
 def format_report(a: dict) -> str:
-    lines: List[str] = []
-    if a["stages"]:
-        lines.append("Fig. 3 stage attribution (ms, per op kind)")
-        hdr = f"{'op':>10} " + " ".join(f"{s:>10}" for s in STAGES) \
-            + f" {'sum':>10}"
-        lines.append(hdr)
-        for op in sorted(a["stages"]):
-            row = a["stages"][op]
-            tot = sum(row.values())
-            lines.append(f"{op:>10} "
-                         + " ".join(_fmt_ms(row[s]) for s in STAGES)
-                         + f" {_fmt_ms(tot)}")
-            if tot > 0:
-                lines.append(f"{'':>10} "
-                             + " ".join(f"{row[s] / tot:>9.1%} "
-                                        for s in STAGES))
-        for op in sorted(a["regions"]):
-            reg = a["regions"][op]
-            parts = ", ".join(f"{k}={1e3 * v:.2f}ms"
-                              for k, v in sorted(reg.items()))
-            lines.append(f"{op:>10} regions: {parts}")
-        lines.append("")
-    else:
-        lines.append("no stage events (run serve with --profile-stages "
-                     "for Fig. 3 attribution)")
-        lines.append("")
-    lines.append("latency decomposition: queue wait vs device wall")
-    lines.append(f"{'op':>10} {'waits':>7} {'wait_ms':>10} "
-                 f"{'batches':>8} {'device_ms':>10} {'mean_lat_ms':>12}")
+    lines = ["latency decomposition: queue wait vs device wall",
+             f"{'op':>10} {'waits':>7} {'wait_ms':>10} "
+                 f"{'batches':>8} {'device_ms':>10} {'mean_lat_ms':>12}"]
     ops = sorted(set(a["queue_wait"]) | set(a["device_wall"])
                  | set(a["complete"]))
     for op in ops:

@@ -1,7 +1,7 @@
 """Span tracing with Chrome trace-event export (Perfetto-loadable).
 
 One :class:`Tracer` instance is threaded through the serving stack
-(HEServer → OpEngine → TableCache → StageTimer) and records everything
+(HEServer → OpEngine → TableCache) and records everything
 as complete events — ph "X" with explicit pid/tid/ts/dur/name/cat —
 because a single uniform event shape keeps downstream consumers
 (tools/check_docs.py's OBS_SCHEMA, repro.obs.report, Perfetto) trivial:
@@ -15,6 +15,15 @@ name to a small int and emits one "M"/thread_name metadata record per
 lane so Perfetto shows the name. Metadata records carry the same
 ts/dur/cat keys as everything else — one schema, no special cases.
 
+Profiler mirror: every live span also opens a `jax.profiler`
+host annotation (TraceMe) named ``hserve.<span name>``, with the span's
+args as its metadata, for as long as it is open, so a
+`jax.profiler.trace` of the server holds the program's spans on the
+same clock as the device's operations. Events recorded
+with explicit timestamps (`event()`, `instant()`) stay JSON-only. The
+mirror uses jax only when something else has imported it, so this
+module still imports on a jax-free host.
+
 The DISABLED tracer is free: `span()`/`event()`/`instant()` return a
 shared no-op singleton and append nothing, so `serve --he` without
 `--trace` allocates zero objects per request on the hot path (pinned by
@@ -24,10 +33,14 @@ tests/test_obs.py).
 from __future__ import annotations
 
 import json
+import sys
 import time
 from typing import Callable, Dict, List, Optional
 
-__all__ = ["Span", "Tracer"]
+__all__ = ["Span", "Tracer", "MIRROR_PREFIX", "span"]
+
+# profiler-side name of a span: MIRROR_PREFIX + span name
+MIRROR_PREFIX = "hserve."
 
 # Metadata records reuse the full event schema (ts/dur keys and all) so
 # every element of traceEvents validates against the same OBS_SCHEMA.
@@ -39,10 +52,12 @@ class Span:
     context exit. The no-op singleton (`tracer disabled`) shares this
     class with `_live=False` so the hot path has no isinstance checks."""
 
-    __slots__ = ("_tracer", "name", "cat", "lane", "args", "_t0", "_live")
+    __slots__ = ("_tracer", "name", "cat", "lane", "args", "_t0", "_live",
+                 "_mirror")
 
     def __init__(self, tracer: Optional["Tracer"], name: str, cat: str,
-                 lane: str, args: Optional[dict], t0: float, live: bool):
+                 lane: str, args: Optional[dict], t0: float, live: bool,
+                 mirror=None):
         self._tracer = tracer
         self.name = name
         self.cat = cat
@@ -50,6 +65,7 @@ class Span:
         self.args = args
         self._t0 = t0
         self._live = live
+        self._mirror = mirror
 
     def end(self, **extra_args) -> None:
         if not self._live:
@@ -61,6 +77,8 @@ class Span:
             args = {**(args or {}), **extra_args}
         tr.event(self.name, cat=self.cat, lane=self.lane, ts=self._t0,
                  dur=tr.clock() - self._t0, args=args)
+        if self._mirror is not None:
+            self._mirror.__exit__(None, None, None)
 
     def __enter__(self) -> "Span":
         return self
@@ -70,6 +88,26 @@ class Span:
 
 
 _NULL_SPAN = Span(None, "", "", "", None, 0.0, live=False)
+
+
+def _open_mirror(name: str, args: Optional[dict]):
+    """An entered `jax.profiler.TraceAnnotation` named MIRROR_PREFIX +
+    name carrying args, or None where jax has not been imported."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    ann = jax.profiler.TraceAnnotation(MIRROR_PREFIX + name, **(args or {}))
+    ann.__enter__()
+    return ann
+
+
+def span(tracer: Optional["Tracer"], name: str, *, cat: str, lane: str,
+         args: Optional[dict] = None) -> Span:
+    """`tracer.span(...)`, or the shared no-op span when tracer is None
+    (call sites hold an optional tracer)."""
+    if tracer is None:
+        return _NULL_SPAN
+    return tracer.span(name, cat=cat, lane=lane, args=args)
 
 
 class Tracer:
@@ -136,10 +174,13 @@ class Tracer:
 
     def span(self, name: str, *, cat: str, lane: str,
              args: Optional[dict] = None) -> Span:
-        """Open a span at now(); closes (and records) on end()/exit."""
+        """Open a span at now(); closes (and records) on end()/exit.
+        Mirrored into the profiler while open (module docstring)."""
         if not self.enabled:
             return _NULL_SPAN
-        return Span(self, name, cat, lane, args, self.clock(), live=True)
+        mirror = _open_mirror(name, args)
+        return Span(self, name, cat, lane, args, self.clock(), live=True,
+                    mirror=mirror)
 
     def instant(self, name: str, *, cat: str, lane: str,
                 args: Optional[dict] = None) -> None:

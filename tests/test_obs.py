@@ -1,16 +1,20 @@
 """repro.obs tests: bounded reservoirs, the metrics registry, tracer
-span semantics under a fake clock, StageTimer attribution, StepMonitor
-re-anchoring, the offline report, and the traced+profiled serving path
-(bitwise vs plain serving, all eight lifecycle phases, schema-valid
-trace events).
+span semantics under a fake clock, the Fig. 3 stage scopes of the
+compiled mul step, the profiler mirror of the server's spans,
+StepMonitor re-anchoring, the offline report, and the traced serving
+path (bitwise vs plain serving, all eight lifecycle phases, the spans of
+one batch, schema-valid trace events).
 
 The 8-device lifecycle check runs through the shared
 run_in_8dev_subprocess harness (tests/conftest.py): a fresh interpreter
 with XLA_FLAGS=--xla_force_host_platform_device_count=8.
 """
 
+import contextlib
+import glob
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -21,10 +25,13 @@ import jax
 
 from repro.core import heaan as H
 from repro.core import test_params as small_params
+from repro.core.context import make_context
 from repro.core.keys import keygen
 from repro.core.rotate import rot_keygen
+from repro.dist.he_pipeline import (he_input_specs, he_static,
+                                    make_he_mul_step, runtime_tables)
 from repro.hserve import HEServer, ServeMetrics
-from repro.obs import MetricsRegistry, Reservoir, StageTimer, Tracer
+from repro.obs import MetricsRegistry, Reservoir, Tracer
 from repro.obs.report import analyze, format_report, load_events
 from repro.obs.trace import _NULL_SPAN
 from repro.runtime.monitor import Heartbeat, StepMonitor
@@ -37,6 +44,9 @@ PARAMS = small_params(logN=4, beta_bits=32)   # N=16, n_slots=8, L=5
 EVENT_KEYS = ("pid", "tid", "ts", "dur", "name", "cat")
 LIFECYCLE = {"submit", "enqueue", "bucket_wait", "flush",
              "batch_assemble", "dispatch", "device_wall", "complete"}
+# the spans of one batch through HEServer.poll on the synchronous path
+BATCH_SPANS = {"poll", "batch_assemble", "dispatch", "h2d", "launch",
+               "wait", "retire"}
 
 
 @pytest.fixture(scope="module")
@@ -254,7 +264,8 @@ def test_tracer_caps_retained_events():
 
 def test_obs_package_imports_without_jax():
     """Import contract: the frontend metrics path must be loadable on a
-    jax-free host (jax only loads lazily inside StageTimer.timed)."""
+    jax-free host (the tracer's profiler mirror uses jax only once
+    something else has imported it)."""
     code = ("import sys; import repro.obs; "
             "print('jax' in sys.modules)")
     env = dict(os.environ)
@@ -267,50 +278,95 @@ def test_obs_package_imports_without_jax():
 
 
 # --------------------------------------------------------------------------
-# StageTimer: attribution scoping, pausing, tracer coupling
+# Fig. 3 stage scopes in the compiled step; the profiler mirror of spans
 # --------------------------------------------------------------------------
 
-def test_stage_timer_attribution_and_regions():
-    clk = _FakeClock(tick=0.5)
-    tr = Tracer(clock=clk)
-    st = StageTimer(tracer=tr, clock=clk)
-    with st.op("mul"):
-        assert st.timed("crt", lambda: 7) == 7   # returns the thunk's value
-        st.timed("ntt", lambda: None)
-        with st.region("region1"):
-            st.timed("modmul", lambda: None)
-    with st.op("rotate"):
-        st.timed("ntt", lambda: None)
-    s = st.summary()
-    # every timed() call spans exactly two clock reads → 0.5 s each
-    assert s["stages"]["mul"] == {"crt": 0.5, "ntt": 0.5,
-                                  "modmul": 0.5, "icrt": 0.0}
-    assert s["calls"]["mul"]["crt"] == 1
-    assert s["stages"]["rotate"]["ntt"] == 0.5
-    assert st.stage_total("mul") == pytest.approx(1.5)
-    assert st.stage_total("absent") == 0.0
-    # the region envelops its inner stage (region wall > stage wall)
-    assert s["regions"]["mul"]["region1"] >= 0.5
-    # stage spans landed on the tracer's "stage" lane, tagged by op
-    stage_evs = [e for e in tr.events
-                 if e["ph"] == "X" and e["cat"] == "stage"]
-    assert {(e["name"], e["args"]["op"]) for e in stage_evs} == {
-        ("crt", "mul"), ("ntt", "mul"), ("modmul", "mul"),
-        ("region1", "mul"), ("ntt", "rotate")}
-    with pytest.raises(ValueError):
-        st.timed("keyswitch", lambda: None)
-    st.reset()
-    assert st.summary() == {"stages": {}, "calls": {}, "regions": {}}
+def _compiled_mul_hlo():
+    """Optimized HLO text of the toy-size fused mul step (CPU)."""
+    _, _, evk = keygen(PARAMS, seed=0)
+    st = he_static(PARAMS, PARAMS.logQ)
+    mesh = make_mesh((1, 1), ("data", "model"))
+    t1, t2, ek = runtime_tables(make_context(PARAMS, PARAMS.logQ), evk)
+    return jax.jit(make_he_mul_step(st, mesh)).lower(
+        t1, t2, ek, *he_input_specs(st, 2)).compile().as_text()
 
 
-def test_stage_timer_pause_suppresses_recording():
-    st = StageTimer(clock=_FakeClock())
-    with st.op("mul"), st.pause():               # warm-up runs book nothing
-        assert st.timed("crt", lambda: 3) == 3
-        with st.region("region1"):
-            pass
-    assert st.stage_total("mul") == 0.0
-    assert st.summary()["regions"] == {}
+@pytest.fixture(scope="module")
+def mul_hlo():
+    return _compiled_mul_hlo()
+
+
+@pytest.mark.parametrize("stage", ["crt", "ntt", "intt", "modmul", "icrt"])
+def test_stage_scope_in_compiled_mul_step(mul_hlo, stage):
+    """Each Fig. 3 stage scope reaches the op_name metadata of the
+    compiled mul step's ops, inside one of the two Fig. 2 regions."""
+    names = re.findall(r'op_name="([^"]*)"', mul_hlo)
+    scoped = [n for n in names if f"he.{stage}/" in n + "/"]
+    assert scoped, f"no op of the compiled step is under he.{stage}"
+    assert all("he.region1/" in n or "he.region2/" in n for n in scoped)
+
+
+def test_stage_scopes_leave_the_compiled_program_unchanged(mul_hlo,
+                                                           monkeypatch):
+    """Scopes are metadata only: with named_scope a no-op the compiled
+    step is the same program, instruction for instruction."""
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare = _compiled_mul_hlo()
+
+    def program(text):
+        body = text[text.index("\n%"):]        # after the frame tables
+        return re.sub(r", metadata=\{[^}]*\}", "", body)
+
+    assert "he.crt" not in bare and "he.crt" in mul_hlo
+    assert program(bare) == program(mul_hlo)
+
+
+def test_server_spans_are_mirrored_into_the_profiler(tmp_path, keys):
+    """A CPU jax.profiler trace of one served batch holds the tracer's
+    spans as hserve.* host events, on the profiler's clock."""
+    from jax.profiler import ProfileData
+    _, pk, evk, _ = keys
+    mesh = make_mesh((1, 1), ("data", "model"))
+    srv = HEServer(PARAMS, evk, mesh=mesh, batch=2, tracer=Tracer())
+    c1, c2 = _enc(pk, 1), _enc(pk, 2)
+    srv.submit_mul(c1, c2)
+    srv.drain()                                  # warm: compile outside
+    srv.submit_mul(c1, c2)
+    srv.submit_mul(c2, c1)
+    jax.profiler.start_trace(str(tmp_path))
+    assert len(srv.poll()) == 2
+    jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    host = {e.name for p in ProfileData.from_file(path).planes
+            if p.name.startswith("/host:") for ln in p.lines
+            for e in ln.events}
+    want = {"hserve." + n for n in BATCH_SPANS}
+    assert want <= host, sorted(want - host)
+    # explicit-timestamp lifecycle events stay JSON-only
+    assert "hserve.device_wall" not in host
+
+
+def test_h2d_bytes_counts_the_placed_arrays(keys):
+    """engine.h2d_bytes grows by exactly the bytes of the batch arrays
+    handed to device_put."""
+    _, pk, evk, _ = keys
+    mesh = make_mesh((1, 1), ("data", "model"))
+    srv = HEServer(PARAMS, evk, mesh=mesh, batch=2)
+    c1, c2 = _enc(pk, 1), _enc(pk, 2)
+    srv.submit_mul(c1, c2)
+    srv.drain()
+    counter = srv.registry.counter("engine.h2d_bytes")
+    before = counter.value
+    srv.submit_mul(c1, c2)
+    b = srv._pop_assemble(srv.queue.any_key(), "drain")
+    placed = srv.engine._place(b)
+    assert counter.value - before == sum(
+        np.asarray(v).nbytes for v in placed.values())
+    assert counter.value - before == 4 * 2 * PARAMS.N \
+        * PARAMS.qlimbs(PARAMS.logQ) * 4
+    assert srv.registry.snapshot()["counters"]["engine.h2d_bytes"] \
+        == counter.value
 
 
 # --------------------------------------------------------------------------
@@ -375,13 +431,8 @@ def test_report_aggregates_stage_and_lifecycle_events(tmp_path):
 
     doc = {"traceEvents": [
         {"pid": 1, "tid": 0, "ts": 0.0, "dur": 0.0, "name": "thread_name",
-         "cat": "__metadata", "ph": "M", "args": {"name": "stage"}},
-        ev("crt", "stage", 0.010, op="mul"),
-        ev("ntt", "stage", 0.030, op="mul"),
-        ev("ntt", "stage", 0.020, op="mul"),     # fwd + inverse both book
-        ev("modmul", "stage", 0.015, op="mul"),
-        ev("icrt", "stage", 0.005, op="mul"),
-        ev("region2", "stage", 0.040, op="mul"),
+         "cat": "__metadata", "ph": "M", "args": {"name": "server"}},
+        ev("poll", "server", 0.300),             # spans: not in the table
         ev("bucket_wait", "lifecycle", 0.200, op="mul"),
         ev("device_wall", "lifecycle", 0.090, op="mul"),
         ev("complete", "lifecycle", 0.0, op="mul", latency_s=0.3),
@@ -394,22 +445,20 @@ def test_report_aggregates_stage_and_lifecycle_events(tmp_path):
     events = load_events(path)
     assert all(e["ph"] == "X" for e in events)   # metadata filtered out
     a = analyze(events)
-    assert a["stages"]["mul"] == pytest.approx(
-        {"crt": 0.010, "ntt": 0.050, "modmul": 0.015, "icrt": 0.005})
-    assert a["regions"]["mul"]["region2"] == pytest.approx(0.040)
+    assert set(a) == {"queue_wait", "device_wall", "complete"}
     assert a["queue_wait"]["mul"] == {
         "total_s": pytest.approx(0.2), "n": 1}
     assert a["device_wall"]["mul"]["batches"] == 1
     assert a["complete"]["mul"]["n"] == 2
     assert a["complete"]["mul"]["latency_total_s"] == pytest.approx(0.4)
     rep = format_report(a)
-    assert "Fig. 3 stage attribution" in rep
+    assert "Fig. 3" not in rep
     assert "queue wait vs device wall" in rep
     assert "mul" in rep
 
 
 # --------------------------------------------------------------------------
-# end to end: traced + stage-profiled serving
+# end to end: traced serving
 # --------------------------------------------------------------------------
 
 def _drive(server, pk):
@@ -422,15 +471,14 @@ def _drive(server, pk):
 
 
 def test_traced_profiled_serving_is_bitwise_with_full_lifecycle(keys):
-    """`tracer + profile_stages` serving returns bit-identical
-    ciphertexts to the plain fused path, records every lifecycle phase
-    with schema-valid events, books Fig. 3 stage time for every staged
-    op, and snapshots the whole stack through one registry."""
+    """Traced serving returns bit-identical ciphertexts to the untraced
+    path, records every lifecycle phase and the spans of each batch
+    through poll with schema-valid events, and snapshots the whole stack
+    through one registry."""
     _, pk, evk, rks = keys
     mesh = make_mesh((1, 1), ("data", "model"))
     tr = Tracer()
-    srv = HEServer(PARAMS, evk, rks, mesh=mesh, batch=2,
-                   tracer=tr, profile_stages=True)
+    srv = HEServer(PARAMS, evk, rks, mesh=mesh, batch=2, tracer=tr)
     outs = _drive(srv, pk)
     plain = HEServer(PARAMS, evk, rks, mesh=mesh, batch=2)
     outs0 = _drive(plain, pk)
@@ -439,19 +487,22 @@ def test_traced_profiled_serving_is_bitwise_with_full_lifecycle(keys):
     xs = [e for e in tr.events if e["ph"] == "X"]
     names = {e["name"] for e in xs}
     assert LIFECYCLE <= names                    # all eight phases
+    assert BATCH_SPANS | {"warm_compile"} <= names
     assert all(all(k in e for k in EVENT_KEYS) for e in tr.events)
 
-    st = srv.engine.stage_timer
-    summ = st.summary()
+    # one poll, launch, wait and retire span per batch (three batches:
+    # mul, mul, rotate at batch 2), nested inside their poll
     per_op = srv.metrics.summary()["per_op"]
-    for op in ("mul", "rotate"):
-        assert st.stage_total(op) > 0.0
-        assert st.stage_total(op) <= per_op[op]["wall_s"]
-    # mul books both Fig. 2 regions and all four Fig. 3 buckets
-    assert set(summ["regions"]["mul"]) == {"region1", "region2"}
-    assert all(v > 0.0 for v in summ["stages"]["mul"].values())
-    # rotate has no ciphertext-product region and no region-1 modmul
-    assert summ["stages"]["rotate"]["modmul"] > 0.0   # key switch only
+    n_batches = sum(d["batches"] for d in per_op.values())
+    by_name = {}
+    for e in xs:
+        by_name.setdefault(e["name"], []).append(e)
+    for name in ("launch", "wait", "retire"):
+        assert len(by_name[name]) == n_batches, name
+    polls = by_name["poll"]
+    for e in by_name["retire"] + by_name["h2d"]:
+        assert any(p["ts"] <= e["ts"] and e["ts"] + e["dur"]
+                   <= p["ts"] + p["dur"] for p in polls)
 
     snap = srv.registry.snapshot()
     for key in ("counters", "gauges", "histograms", "serve", "cache",
@@ -459,22 +510,22 @@ def test_traced_profiled_serving_is_bitwise_with_full_lifecycle(keys):
         assert key in snap, key
     assert snap["counters"]["serve.requests"] == 3
     assert snap["histograms"]["serve.batch.wall_s"]["count"] >= 2
-    # the server's stats() surface carries the stage summary too
-    assert srv.stats()["stages"]["stages"]["mul"]["ntt"] > 0.0
+    assert snap["counters"]["engine.h2d_bytes"] > 0
+    assert "stages" not in srv.stats()
 
 
 def test_trace_roundtrips_through_the_offline_report(tmp_path, keys):
     _, pk, evk, rks = keys
     mesh = make_mesh((1, 1), ("data", "model"))
     tr = Tracer()
-    srv = HEServer(PARAMS, evk, rks, mesh=mesh, batch=2,
-                   tracer=tr, profile_stages=True)
+    srv = HEServer(PARAMS, evk, rks, mesh=mesh, batch=2, tracer=tr)
     _drive(srv, pk)
     path = str(tmp_path / "trace.json")
     n = tr.write(path)
     assert n == len(tr.events)
-    a = analyze(load_events(path))
-    assert a["stages"]["mul"]["ntt"] > 0.0
+    events = load_events(path)
+    assert BATCH_SPANS <= {e["name"] for e in events}
+    a = analyze(events)
     assert a["complete"]["mul"]["n"] == 2
     assert a["device_wall"]["mul"]["batches"] >= 1
     assert a["queue_wait"]["mul"]["n"] == 2
@@ -500,10 +551,10 @@ def test_session_publishes_client_counters(keys):
 
 def test_traced_serving_on_8_device_mesh_records_all_phases(
         run_in_8dev_subprocess):
-    """Sharded (2, 4)-mesh serving with the tracer and stage profiler
-    on: results stay bitwise vs the core references, every one of the
-    eight lifecycle phases lands in the trace, every event carries the
-    full key set, and mul books stage time."""
+    """Sharded (2, 4)-mesh serving with the tracer on: results stay
+    bitwise vs the core references, every one of the eight lifecycle
+    phases and the spans of each batch through poll land in the trace,
+    and every event carries the full key set."""
     res = run_in_8dev_subprocess("""
         from repro.core import heaan as H
         from repro.core import test_params
@@ -518,7 +569,7 @@ def test_traced_serving_on_8_device_mesh_records_all_phases(
         mesh = make_mesh((2, 4), ("data", "model"))
         tr = Tracer()
         server = HEServer(params, evk, rks, mesh=mesh, batch=2,
-                          tracer=tr, profile_stages=True)
+                          tracer=tr)
 
         rng = np.random.default_rng(7)
         def enc(seed):
@@ -537,7 +588,6 @@ def test_traced_serving_on_8_device_mesh_records_all_phases(
             return bool((np.asarray(a.ax) == np.asarray(b.ax)).all()
                         and (np.asarray(a.bx) == np.asarray(b.bx)).all())
         keys = ("pid", "tid", "ts", "dur", "name", "cat")
-        st = server.engine.stage_timer
         print(json.dumps({
             "devices": jax.device_count(),
             "bitwise": bitwise(ok_mul, ref_mul) and bitwise(ok_rot,
@@ -546,11 +596,10 @@ def test_traced_serving_on_8_device_mesh_records_all_phases(
                              if e["ph"] == "X"}),
             "bad_events": sum(1 for e in tr.events
                               if not all(k in e for k in keys)),
-            "stage_mul_s": st.stage_total("mul"),
         }))
     """)
     assert res["devices"] == 8
     assert res["bitwise"] is True
     assert res["bad_events"] == 0
     assert LIFECYCLE <= set(res["names"])
-    assert res["stage_mul_s"] > 0.0
+    assert BATCH_SPANS <= set(res["names"])

@@ -132,7 +132,6 @@ def _fake_hserver(schedule: bool, batch: int):
     class FakeEngine:
         n_compiled = 0
         compile_s = 0.0
-        profile_stages = False
 
         def __init__(self):
             self.batches = []        # [(key, [tag-or-None, ...])]
@@ -141,6 +140,9 @@ def _fake_hserver(schedule: bool, batch: int):
             assert all(r.bucket_key == b.key for r in b.requests), \
                 "co-batching merged requests with different bucket keys"
             return Inflight(batch=b, ax=None, bx=None, t0=0.0)
+
+        def block(self, inf):
+            return 0.0
 
         def wait(self, inf):
             b = inf.batch
