@@ -11,7 +11,7 @@ no twiddle build, milliseconds per cell), statically parses the
 optimized HLO with `launch.hlo_analysis`, and compares against the
 analytic prediction `dist.sharding.he_expected_collectives` derives
 from the paper's Fig. 2 dataflow (only iCRT's cross-prime accumulation
-communicates: 5 all-reduced tensors over model-axis groups per reduction).
+communicates: one all-reduced tensor over model-axis groups per reduction).
 
 Findings ship as the HS1xx rule series through the hslint Diagnostic
 machinery:

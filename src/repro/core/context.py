@@ -169,8 +169,8 @@ class IcrtTables:
     pdivp: np.ndarray             # (np, plimbs)  limbs of P/p_j
     P_limbs: np.ndarray           # (accum_limbs,)
     P_half_limbs: np.ndarray      # (accum_limbs,)  floor(P/2)
-    quot_fix: np.ndarray          # (np, 2)  floor(β²/p_j) — the TPU kernel's
-    #                               fixed-point quotient (no f64 on TPU)
+    quot_fix: np.ndarray          # (np, 2)  floor(β²/p_j) — the fixed-point
+    #                               quotient of "gemm8" and the TPU kernel
 
 
 @lru_cache(maxsize=None)

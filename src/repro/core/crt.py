@@ -24,10 +24,15 @@ iCRT strategies:
                model-sharded prime axis lowers to all-reduces.
   - "matmul" : Algo 6 realized as integer GEMMs on 16-bit table halves
                (β=2³² only) — N·PLimbs parallelism handed to the MXU/BLAS.
+  - "gemm8"  : Algo 6 and the quotient as one exact bf16 GEMM on 8-bit
+               pieces with f32 accumulation (β=2³² only; u64 words fall
+               back to "sum16"). The MXU does the sums over primes, and the
+               quotient comes from the fixed-point ⌊β²/p_j⌋ table, so no
+               f64 op is left. The served default.
 
 The "matmul" strategies run their GEMMs in u64, which the TPU compiler
 refuses; they stay for the Table VII–IX ladders. The served defaults are
-"acc3" CRT and "sum16" iCRT.
+"acc3" CRT and "gemm8" iCRT; "sum16" is kept as its bitwise oracle.
 
 All paths are exact; tests cross-check every strategy against python-int
 oracles and against each other.
@@ -140,30 +145,42 @@ def icrt(r: jnp.ndarray, tabs: IcrtTables, primes: jnp.ndarray,
          inv_P: jnp.ndarray, inv_P_shoup: jnp.ndarray,
          pdivp: jnp.ndarray, P_limbs: jnp.ndarray, P_half: jnp.ndarray,
          p_inv_f64: jnp.ndarray, out_limbs: int,
-         *, strategy: str = "sum16") -> jnp.ndarray:
+         *, strategy: str = "gemm8",
+         quot_fix: jnp.ndarray | None = None) -> jnp.ndarray:
     """Reconstruct centered BigInts from RNS residues (paper Algo 5/6).
 
     r: (np, N). Returns (N, out_limbs) two's-complement (low limbs of the
     centered value — callers mask to mod-q or shift for key-switching).
+    `quot_fix` (np, 2) is ⌊β²/p_j⌋, which "gemm8" reads in place of
+    `p_inv_f64`; it defaults to `tabs.quot_fix`.
     """
     if r.dtype == jnp.uint64 and strategy == "matmul":
         strategy = "acc3"
+    if r.dtype == jnp.uint64 and strategy == "gemm8":
+        strategy = "sum16"      # 8-bit pieces of a 32-bit word only
+    if quot_fix is None:
+        quot_fix = jnp.asarray(tabs.quot_fix)
     return _icrt_jit(r, primes, inv_P, inv_P_shoup, pdivp, P_limbs, P_half,
-                     p_inv_f64, out_limbs=out_limbs,
+                     p_inv_f64, quot_fix, out_limbs=out_limbs,
                      accum_limbs=tabs.accum_limbs, strategy=strategy)
 
 
 @partial(jax.jit,
          static_argnames=("out_limbs", "accum_limbs", "strategy"))
 def _icrt_jit(r, primes, inv_P, inv_P_shoup, pdivp, P_limbs, P_half,
-              p_inv_f64, *, out_limbs: int, accum_limbs: int, strategy: str):
+              p_inv_f64, quot_fix, *, out_limbs: int, accum_limbs: int,
+              strategy: str):
     npn, N = r.shape
     dt = r.dtype
-    beta = jnp.dtype(dt).itemsize * 8
 
     # (1) Hadamard: temp[j,n] = mod(r[j,n]·(P/p_j)⁻¹, p_j)   [Shoup]
     temp = shoup_modmul(r, inv_P[:, None], inv_P_shoup[:, None],
                         primes[:, None])
+
+    if strategy == "gemm8":
+        # (2)+(3) accum and the fixed-point quotient from one GEMM
+        accum, s = _accum_gemm8(temp, pdivp, quot_fix, accum_limbs)
+        return finalize_accum(accum, s, P_limbs, P_half, out_limbs)
 
     # (2) accum[n] = Σ_j temp[j,n]·(P/p_j)  — strategy-dependent
     if strategy == "sum16":
@@ -188,8 +205,8 @@ def finalize_accum(accum, s, P_limbs, P_half, out_limbs: int):
     """accum − s·P with ±1 quotient corrections, center-lift, truncate.
 
     Shared by the pure-JAX iCRT and the Pallas iCRT tail. `s` may come from
-    the f64 quotient (CPU) or the fixed-point integer quotient (TPU kernel);
-    both are exact after the correction ladder.
+    the f64 quotient or the fixed-point integer quotient ("gemm8", the TPU
+    kernel); both are exact after the correction ladder.
     """
     N, accum_limbs = accum.shape
     sp = bigint.mul_word(jnp.broadcast_to(P_limbs, (N, accum_limbs)), s)
@@ -228,7 +245,6 @@ def _accum_sum16(temp, pdivp, accum_limbs):
     and 48 of limb k.
     """
     npn, N = temp.shape
-    PL = pdivp.shape[1]
     dt = temp.dtype
     h = jnp.dtype(dt).itemsize * 4
     mask = jnp.asarray((1 << h) - 1, dt)
@@ -238,10 +254,103 @@ def _accum_sum16(temp, pdivp, accum_limbs):
     def total(x):
         return jnp.sum(x, axis=0, dtype=dt)                  # (N, PL)
 
-    sums = [_placed(total(lo & mask), 0, accum_limbs),
-            _placed(total(lo >> h), 0, accum_limbs),
-            _placed(total(hi & mask), 0, accum_limbs),
-            _placed(total(hi >> h), 0, accum_limbs)]
+    return _carry16([total(lo & mask), total(lo >> h), total(hi & mask),
+                     total(hi >> h)], accum_limbs)
+
+
+def _accum_gemm8(temp, pdivp, quot_fix, accum_limbs, chunk: int = 8192):
+    """Algo 6's accumulation and its quotient as one exact GEMM on the MXU.
+
+    Both are sums over primes of temp[j,n] times a table word: the limbs
+    of P/p_j (`pdivp`, PL columns) for accum = Σ_j temp_j·(P/p_j), and
+    the two limbs of ⌊β²/p_j⌋ (`quot_fix`) for the quotient, the
+    fixed-point stand-in for the f64 Σ_j temp_j/p_j that the Pallas
+    kernel also uses. Exactness, with β = 2^32:
+
+      - temp and the W = PL + 2 table columns are cut into 8-bit pieces,
+        temp = Σ_a t_a·2^(8a) and T[j,k] = Σ_b T_b[j,k]·2^(8b), a, b < 4.
+        Integers 0..255 are exact in bf16 (8 significant bits), so the
+        operands are fed as bf16: no matmul-precision setting can round
+        them.
+      - one dot_general contracts the prime axis: out[a,n,b,k] =
+        Σ_j t_a[j,n]·T_b[j,k], accumulated in f32. Each product is below
+        2^16, and a sum of np of them is at most np·255² < 2^24 for
+        np ≤ 258 (the largest np served is 122), so every partial sum
+        is an integer f32 holds exactly, in any order of addition.
+      - on a mesh whose model axis shards the primes, the partitioner
+        splits the contraction into per-shard partial sums and
+        all-reduces them in f32. The partial sums are non-negative and
+        bounded by the total, below 2^24, so the all-reduce is exact too.
+      - converted to u32, the pieces that share a bit offset
+        32k + 8c (c = a + b ≤ 6) are added: at most four terms below
+        2^23, so each group sum G_c is below 2^25. The odd groups are
+        split at a byte so that everything lands on the 16-bit offsets
+        0, 16, 32, 48 of column k, four word sums below 2^26, and the
+        `_carry16` pass that `_accum_sum16` uses places them in limbs.
+      - the quotient is word 2 of Σ_j temp_j·⌊β²/p_j⌋ (< np·β² < β³),
+        which is ⌊Σ_j temp_j/p_j⌋ or one less; `finalize_accum`'s ±1
+        ladder makes the reduction exact either way.
+
+    The GEMM's f32 output is 16·W words per coefficient, so it runs over
+    `chunk` coefficients at a time (`lax.map`): at Table III, batch 8,
+    a whole region-2 call would hold 3.9 GB of it at once.
+
+    Returns (accum (N, accum_limbs), s (N,)).
+    """
+    npn, N = temp.shape
+    PL = pdivp.shape[1]
+    dt = temp.dtype
+    assert dt == jnp.uint32, "8-bit pieces of a 32-bit word"
+    assert npn * 255 * 255 < (1 << 24), "f32 piece sums would round"
+    table = jnp.concatenate([pdivp, quot_fix], axis=1)        # (np, W)
+    W = table.shape[1]
+    rhs = jnp.moveaxis(_bytes_bf16(table), 0, 1).reshape(npn, 4 * W)
+    if N <= chunk or N % chunk:
+        sums = _gemm8_sums(temp, rhs)
+    else:
+        parts = jax.lax.map(
+            lambda t: _gemm8_sums(t, rhs),
+            jnp.moveaxis(temp.reshape(npn, N // chunk, chunk), 1, 0))
+        sums = jnp.moveaxis(parts, 0, 1).reshape(4, N, W)
+    accum = _carry16(sums[:, :, :PL], accum_limbs)
+    quot = _carry16(sums[:, :, PL:], 3)
+    return accum, quot[:, 2]
+
+
+def _bytes_bf16(x):
+    """(...) u32 words -> (4, ...) bf16 bytes, least significant first."""
+    shifts = jnp.arange(0, 32, 8, dtype=x.dtype).reshape((4,) + (1,) * x.ndim)
+    return ((x[None] >> shifts) & jnp.asarray(0xFF, x.dtype)).astype(
+        jnp.bfloat16)
+
+
+def _gemm8_sums(temp, rhs):
+    """(np, n) u32 and the (np, 4W) byte planes of the table -> (4, n, W)
+    word sums at bit offsets 0, 16, 32, 48 of each column."""
+    dt = temp.dtype
+    n = temp.shape[1]
+    W = rhs.shape[1] // 4
+    out = jax.lax.dot_general(
+        _bytes_bf16(temp), rhs, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)              # (4, n, 4W)
+    out = out.astype(dt).reshape(4, n, 4, W)
+    g = [sum(out[a, :, c - a] for a in range(max(0, c - 3), min(c, 3) + 1))
+         for c in range(7)]                              # G_c, < 2^25
+    byte = jnp.asarray(0xFF, dt)
+    return jnp.stack([g[0] + ((g[1] & byte) << 8),
+                      g[2] + (g[1] >> 8) + ((g[3] & byte) << 8),
+                      g[4] + (g[3] >> 8) + ((g[5] & byte) << 8),
+                      g[6] + (g[5] >> 8)])
+
+
+def _carry16(sums, accum_limbs):
+    """Place four (N, PL) word sums at bit offsets 0, 16, 32 and 48 of
+    limb k, and propagate the carries: -> (N, accum_limbs) BigInt."""
+    N = sums[0].shape[0]
+    dt = sums[0].dtype
+    h = jnp.dtype(dt).itemsize * 4
+    mask = jnp.asarray((1 << h) - 1, dt)
+    sums = [_placed(s, 0, accum_limbs) for s in sums]
 
     def carry_step(carry, col):
         # limb t collects s0[t] + s1[t]·2^h (low half), the high half of

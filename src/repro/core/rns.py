@@ -31,7 +31,7 @@ class PipelineConfig:
     """Paper optimization toggles (§V). Defaults = the served path, which
     compiles for every backend (the TPU refuses the u64 matmul GEMMs)."""
     crt_strategy: str = "acc3"        # acc3 | shoup | mod2 | mod4 | matmul
-    icrt_strategy: str = "sum16"      # sum16 | acc3 | naive | matmul
+    icrt_strategy: str = "gemm8"      # gemm8 | sum16 | acc3 | naive | matmul
     modified_shoup: bool = False      # paper's 3-half-mul Shoup variant
     use_kernels: bool = False         # route stages through Pallas kernels
 

@@ -15,9 +15,10 @@ This is `core.heaan.he_mul` restructured for a device mesh:
 Bitwise contract: the step reuses the exact `core` stage functions (crt,
 ntt, mont pointwise, intt, icrt, BigInt combine) in the same order as
 `core.heaan.he_mul`, and sharding is expressed only through placement
-constraints — integer limb arithmetic partitions exactly, and iCRT's f64
-quotient estimate is followed by exact ±1 corrections — so the sharded
-output equals the single-device reference bit for bit (tests/test_dist.py).
+constraints — integer limb arithmetic partitions exactly, iCRT's f32
+piece sums stay below 2^24, and its quotient estimate is followed by
+exact ±1 corrections — so the sharded output equals the single-device
+reference bit for bit (tests/test_dist.py).
 
 The batched stage wrappers are factored into a :class:`StageFns` bundle
 (``make_stage_fns``) plus a region-2 key-switch factory
@@ -30,12 +31,12 @@ bitwise contract holds on either path).
 
 Table pytree note: ``quot_fix`` (in REGION_TABLE_KEYS since the Pallas
 routing landed) is ⌊β²/p_j⌋ as two β-bit limbs per prime — the
-fixed-point reciprocal the TPU iCRT kernel uses for its quotient
-estimate in place of the reference path's f64 multiply (TPUs have no
-f64). It is built by ``build_icrt_tables`` but depends only on the
-prime, so `repro.hserve.tables.TableCache` row-slices it from one
-resident copy like the prime-pool tables, not per-np like the other
-iCRT entries. See ``IcrtTables.quot_fix`` in `core/context.py` and
+fixed-point reciprocal the served "gemm8" iCRT and the TPU iCRT kernel
+use for their quotient estimate in place of the f64 multiply of the
+other strategies (TPUs have no f64). It is built by
+``build_icrt_tables`` but depends only on the prime, so
+`repro.hserve.tables.TableCache` row-slices it from one resident copy
+like the prime-pool tables, not per-np like the other iCRT entries. See ``IcrtTables.quot_fix`` in `core/context.py` and
 `kernels/icrt/icrt.py`.
 """
 
@@ -151,9 +152,9 @@ def region_tables(ctx: HEContext, region: int) -> Dict[str, np.ndarray]:
         "P_limbs": tabs.P_limbs,
         "P_half_limbs": tabs.P_half_limbs,
         "p_inv_f64": g.p_inv_f64[:npn],
-        # ⌊β²/p_j⌋, the TPU kernel's fixed-point quotient reciprocal (the
-        # no-f64 stand-in for p_inv_f64); per-prime, not per-P — see the
-        # module docstring
+        # ⌊β²/p_j⌋, the fixed-point quotient reciprocal of "gemm8" and the
+        # TPU kernel (the no-f64 stand-in for p_inv_f64); per-prime, not
+        # per-P — see the module docstring
         "quot_fix": tabs.quot_fix,
     }
 
@@ -306,7 +307,7 @@ def _icrt_b(r: jnp.ndarray, t: Dict, tabs: IcrtTables, out_limbs: int,
     return jax.vmap(lambda rr: icrt(
         rr, tabs, t["primes"], t["inv_P"], t["inv_P_shoup"], t["pdivp"],
         t["P_limbs"], t["P_half_limbs"], t["p_inv_f64"],
-        out_limbs=out_limbs, strategy=strategy))(r)
+        out_limbs=out_limbs, strategy=strategy, quot_fix=t["quot_fix"]))(r)
 
 
 def _mont_mul_b(a: jnp.ndarray, b: jnp.ndarray, t: Dict,
@@ -345,7 +346,7 @@ class StageFns:
 
 def make_stage_fns(st: HEStatic, mesh: Mesh, *,
                    crt_strategy: str = "acc3",
-                   icrt_strategy: str = "sum16",
+                   icrt_strategy: str = "gemm8",
                    modified_shoup: bool = False,
                    reduce_scatter_icrt: bool = False,
                    use_kernels: bool = False) -> StageFns:
@@ -443,7 +444,7 @@ def make_keyswitch_step(st: HEStatic, sf: StageFns):
 
 def make_he_mul_step(st: HEStatic, mesh: Mesh, *,
                      crt_strategy: str = "acc3",
-                     icrt_strategy: str = "sum16",
+                     icrt_strategy: str = "gemm8",
                      modified_shoup: bool = False,
                      reduce_scatter_icrt: bool = False,
                      use_kernels: bool = False):

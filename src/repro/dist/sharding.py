@@ -2,8 +2,9 @@
 
 All rules are *placement hints*: they never change values, only where XLA
 puts them, so every sharded computation stays bitwise identical to its
-single-device reference (integer limb arithmetic partitions exactly; the
-one f64 quotient estimate in iCRT is followed by exact ±1 corrections).
+single-device reference (integer limb arithmetic partitions exactly; iCRT's
+f32 piece sums stay below 2^24, and its quotient estimate is followed by
+exact ±1 corrections).
 
 Axis convention (DESIGN.md §5, mirrors the paper's §V thread mapping):
   - "data":  batches — ciphertext pairs per HE-Mul step, LM examples.
@@ -111,21 +112,21 @@ def he_expected_collectives(op: str, mesh: Mesh, params, logq: int, *,
                             batch: int, n_slots: Optional[int] = None
                             ) -> dict:
     """Predicted collective schedule of one served (op, level) cell under
-    the placements above, with the default "sum16" iCRT strategy.
+    the placements above, with the default "gemm8" iCRT strategy at
+    β = 2^32.
 
     Only iCRT's cross-prime accumulation communicates: every residue
     tensor is (B, np, N) with np on "model", every stage before iCRT is
     prime-pointwise, and the batch axes make every op batch-pointwise —
-    so each iCRT reduction lowers to EXACTLY five all-reduced tensors
-    over the model-axis groups (XLA may combine them into fewer tuple
+    so each iCRT reduction lowers to EXACTLY one all-reduced tensor over
+    the model-axis groups (XLA may combine several into fewer tuple
     instructions; `launch.hlo_analysis` counts tensors):
 
-      4 × u32[B_local, N, plimbs]   the half-word piece sums of
-                                    Σ_j x_j·(P/p_j) (plimbs = limb width
-                                    of P/p_j, from
-                                    `core.context.build_icrt_tables`);
-      1 × f64[B_local, N]           the quotient estimate Σ x_j/p_j that
-                                    picks the exact ±1-corrected k·P.
+      f32[B_local, 4, N, 4·(plimbs + 2)]   the partial sums of the
+          byte-piece GEMM (`core.crt._accum_gemm8`): 4 pieces of x_j
+          against 4 pieces of each table column, the plimbs limbs of
+          P/p_j (from `core.context.build_icrt_tables`) and the two
+          limbs of ⌊β²/p_j⌋ that give the quotient.
 
     Wire bytes use the same ring model as `launch.hlo_analysis`
     (all-reduce = 2·S·(g−1)/g per device); B_local is the per-data-shard
@@ -176,12 +177,11 @@ def he_expected_collectives(op: str, mesh: Mesh, params, logq: int, *,
         if not n_r:
             continue
         plimbs = build_icrt_tables(params, npn).plimbs
-        one = 4 * ring(b_local * params.N * plimbs * 4) \
-            + ring(b_local * params.N * 8)
+        one = ring(b_local * 4 * params.N * 4 * (plimbs + 2) * 4)
         per_region.append({"reductions": n_r, "np": npn,
                            "plimbs": plimbs, "bytes_per_reduction": one})
         total += n_r * one
-    return {"kinds": ["all-reduce"], "counts": {"all-reduce": 5 * n_red},
+    return {"kinds": ["all-reduce"], "counts": {"all-reduce": n_red},
             "wire_bytes": total, "n_reductions": n_red, "axis": "model",
             "group_size": g, "per_region": per_region, "allowed": allowed}
 

@@ -300,7 +300,7 @@ class OpEngine:
 
     def __init__(self, params: HEParams, mesh, cache: TableCache, *,
                  use_kernels: bool = False, crt_strategy: str = "acc3",
-                 icrt_strategy: str = "sum16",
+                 icrt_strategy: str = "gemm8",
                  modified_shoup: bool = False, tracer=None, registry=None):
         self.params = params
         self.mesh = mesh

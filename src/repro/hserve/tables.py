@@ -27,10 +27,11 @@ cannot change a single output bit.
 
 A note on ``quot_fix`` (present in the region tables since the Pallas
 kernel routing landed): it is the table of ⌊β²/p_j⌋ as two β-bit limbs,
-one row per prime — the fixed-point reciprocal the TPU iCRT kernel uses
-to estimate the accumulator quotient where the reference path uses an
-f64 multiply (TPUs have no f64; see `kernels/icrt/icrt.py` and
-`IcrtTables.quot_fix` in `core/context.py`). Although it is built by
+one row per prime — the fixed-point reciprocal the served "gemm8" iCRT
+and the Pallas iCRT kernel use to estimate the accumulator quotient
+where the other strategies use an f64 multiply (TPUs have no f64; see
+`core/crt.py`, `kernels/icrt/icrt.py` and `IcrtTables.quot_fix` in
+`core/context.py`). Although it is built by
 ``build_icrt_tables``, it depends only on the prime — not on
 P = ∏ primes — so unlike the other iCRT entries it row-slices from the
 resident set exactly like the prime-pool tables (``_ROW_KEYS`` below),

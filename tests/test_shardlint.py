@@ -292,9 +292,10 @@ def test_shardlint_cli_clean_and_injected_on_8_device_mesh(
     assert res["rc_ok"] == 0 and res["errors_ok"] == 0
     assert res["cells_ok"] == ["add/120/2x4", "mul/120/2x4",
                                "rotate/120/2x4"]
-    # mul at full depth: (3 + 2) iCRT reductions x 5 all-reduced tensors
-    # each, and the measured ring-model bytes equal the analytic prediction
-    assert res["ar_mul"] == 25
+    # mul at full depth: (3 + 2) iCRT reductions x 1 all-reduced tensor
+    # (the byte-piece GEMM's partial sums) each, and the measured
+    # ring-model bytes equal the analytic prediction
+    assert res["ar_mul"] == 5
     assert res["bytes_match"]
     assert res["rc_bad"] == 1 and res["errors_bad"] >= 2
     assert "HS101" in res["rules_bad"]

@@ -118,10 +118,19 @@ def _device_bytes(compiled) -> int:
 
 
 def test_served_mul_step_fits_one_chip_at_table3_batch8(topo, one_chip):
-    """The default-knob mul step (CRT acc3, iCRT sum16, no kernels)
-    compiles for one v5e chip at batch 8 and fits its HBM."""
-    total = _device_bytes(_compile_mul_step(topo, 8))
+    """The default-knob mul step (CRT acc3, iCRT gemm8, no kernels)
+    compiles for one v5e chip at batch 8 and fits its HBM, with room to
+    spare: the byte-piece GEMM runs over coefficient chunks, so its f32
+    output stays a fraction of a region. Its `he.icrt` ops hold no f64
+    and contract bf16 pieces on the MXU (a convolution)."""
+    compiled = _compile_mul_step(topo, 8)
+    total = _device_bytes(compiled)
     assert total < V5E_HBM_BYTES, total
+    assert total < V5E_HBM_BYTES // 2, total
+    icrt = [ln for ln in compiled.as_text().splitlines()
+            if "/he.icrt/" in ln]
+    assert sum(" convolution(" in ln for ln in icrt) >= 5
+    assert not [ln for ln in icrt if "f64[" in ln]
 
 
 def test_kernel_mul_step_fits_one_chip_at_table3_batch8(topo, one_chip,

@@ -117,7 +117,8 @@ def test_crt_strategies(beta, strategy):
 
 
 @pytest.mark.parametrize("beta", [32, 64])
-@pytest.mark.parametrize("strategy", ["sum16", "matmul", "acc3", "naive"])
+@pytest.mark.parametrize("strategy", ["gemm8", "sum16", "matmul", "acc3",
+                                      "naive"])
 def test_crt_icrt_roundtrip_centered(beta, strategy):
     """CRT → iCRT returns the centered value (two's complement truncation)."""
     if beta == 64 and strategy == "matmul":
